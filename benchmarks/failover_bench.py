@@ -43,6 +43,7 @@ ensure_devices()
 
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
+from jax import shard_map  # noqa: E402
 import numpy as np  # noqa: E402
 
 from repro.comm.autotune import CostModel, route_links  # noqa: E402
@@ -50,7 +51,7 @@ from repro.comm.engine import CollectiveEngine, schedules_for  # noqa: E402
 from repro.comm.faults import FaultInjector, FaultSchedule  # noqa: E402
 from repro.comm.topology import MeshTopology  # noqa: E402
 from repro.comm.types import TPU_V5E  # noqa: E402
-from repro.compat import make_mesh, shard_map  # noqa: E402
+from repro.launch.mesh import make_mesh  # noqa: E402
 
 P = jax.sharding.PartitionSpec
 

@@ -53,7 +53,7 @@ def _moe_explicit_section(quick: bool, schedule):
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from repro.comm.engine import CollectiveEngine
-    from repro.compat import make_mesh
+    from repro.launch.mesh import make_mesh
     from repro.core.hpcc import timeit
     from repro.models import moe as MOE
     from repro.train.step import GRADS_CALLSITE, make_dp_train_step_explicit
@@ -164,7 +164,7 @@ def _whole_model_section(quick: bool, schedule):
                                       SP_OUT, SP_QKV, TP_OUT, TP_QKV)
     from repro.comm.engine import CollectiveEngine
     from repro.comm.overlap import pack_buckets
-    from repro.compat import make_mesh
+    from repro.launch.mesh import make_mesh
     from repro.models import moe as MOE
     from repro.models.parallel import ATTN_MODES
     from repro.train.step import (GRADS_CALLSITE,

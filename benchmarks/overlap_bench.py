@@ -34,11 +34,12 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 from jax.sharding import PartitionSpec as P  # noqa: E402
+from jax import shard_map  # noqa: E402
 
 from repro.comm.engine import CollectiveEngine, schedules_for  # noqa: E402
 from repro.comm.overlap import tree_bytes  # noqa: E402
 from repro.comm.types import CommunicationType as CT  # noqa: E402
-from repro.compat import make_mesh, shard_map  # noqa: E402
+from repro.launch.mesh import make_mesh  # noqa: E402
 from repro.core.hpcc import timeit  # noqa: E402
 from repro.core.hpl import run_hpl  # noqa: E402
 from repro.launch.mesh import make_torus_mesh  # noqa: E402

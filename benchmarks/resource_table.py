@@ -9,7 +9,8 @@ of "resources a design consumes" is exactly what the compiled artifact
 reports: bytes per device (fits/doesn't fit), FLOPs, and wire traffic.
 
 This module needs the 512-device placeholder runtime; when invoked under a
-smaller device count it re-execs itself in a fresh interpreter.
+smaller device count it re-execs itself in a fresh interpreter, pinned to
+the CPU backend so it never contends with the parent for an accelerator.
 """
 from __future__ import annotations
 
@@ -60,8 +61,7 @@ def _lower_hpcc():
     m = (n // b // 16) * b
     for ct in (CT.ICI_DIRECT, CT.HOST_STAGED):
         step = ptrans_mod.make_step(mesh, 16,
-                                    CollectiveEngine.for_mesh(mesh, ct),
-                                    interpret=True)
+                                    CollectiveEngine.for_mesh(mesh, ct))
         spec = jax.ShapeDtypeStruct((256, m, m), np.float32)
         with mesh:
             compiled = step.lower(spec, spec).compile()
@@ -74,7 +74,7 @@ def _lower_hpcc():
     for ct, sched in ((CT.ICI_DIRECT, "chain"), (CT.ICI_DIRECT, "native"),
                       (CT.HOST_STAGED, "staged")):
         fact = hpl_mod.make_factorize(mesh, pg=16, nb=n // b, b=b, comm=ct,
-                                      schedule=sched, interpret=True)
+                                      schedule=sched)
         m = (n // b // 16) * b
         spec = jax.ShapeDtypeStruct((256, m, m), np.float32)
         with mesh:
@@ -108,7 +108,7 @@ def main(quick: bool = False, schedule=None):
         with open(cache) as f:
             hpcc = json.load(f)
     else:
-        env = dict(os.environ,
+        env = dict(os.environ, JAX_PLATFORMS="cpu",
                    XLA_FLAGS="--xla_force_host_platform_device_count=512",
                    PYTHONPATH=os.pathsep.join(
                        [os.path.join(os.path.dirname(__file__), "..", "src"),
@@ -119,13 +119,12 @@ def main(quick: bool = False, schedule=None):
             capture_output=True, text=True, env=env,
             cwd=os.path.join(os.path.dirname(__file__), ".."), timeout=3600)
         if proc.returncode:
-            print("HPCC lowering failed:", proc.stderr[-2000:])
-            hpcc = {}
-        else:
-            hpcc = json.loads(proc.stdout.strip().splitlines()[-1])
-            os.makedirs(RESULTS_DIR, exist_ok=True)
-            with open(cache, "w") as f:
-                json.dump(hpcc, f, indent=1)
+            raise RuntimeError("HPCC lowering failed (exit "
+                               f"{proc.returncode}):\n{proc.stderr[-2000:]}")
+        hpcc = json.loads(proc.stdout.strip().splitlines()[-1])
+        os.makedirs(RESULTS_DIR, exist_ok=True)
+        with open(cache, "w") as f:
+            json.dump(hpcc, f, indent=1)
 
     rows = []
     for name, t in hpcc.items():

@@ -312,6 +312,8 @@ def _sweep(modules, quick):
 
 
 def main():
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
     schedule, argv = _parse_schedule(sys.argv[1:])
     quick = "--quick" in argv
     sweep = "--sweep-schedules" in argv

@@ -34,7 +34,7 @@ import numpy as np  # noqa: E402
 
 from repro.comm.callsites import DECODE_MOE, DECODE_OUT, DECODE_QKV  # noqa: E402
 from repro.comm.engine import CollectiveEngine, schedules_for  # noqa: E402
-from repro.compat import make_mesh  # noqa: E402
+from repro.launch.mesh import make_mesh  # noqa: E402
 from repro.configs.qwen3_moe_235b_a22b import tiny  # noqa: E402
 from repro.models import moe as MOE  # noqa: E402
 from repro.models import transformer as T  # noqa: E402

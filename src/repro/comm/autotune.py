@@ -725,7 +725,7 @@ def _measure_op_clean(mesh, op: str, nbytes: int, schedule: str,
     from jax.sharding import PartitionSpec as P
 
     from repro.comm.engine import CollectiveEngine
-    from repro.compat import shard_map
+    from jax import shard_map
     from repro.core.hpcc import timeit
 
     engine = CollectiveEngine.for_mesh(mesh, schedule=schedule)
@@ -1009,7 +1009,7 @@ def autotune_mesh(*, ops: Sequence[str] = ("bcast", "allreduce",
 
     from repro.comm.engine import schedules_for
     from repro.comm.topology import MeshTopology
-    from repro.compat import make_mesh
+    from repro.launch.mesh import make_mesh
 
     default_sizes = sizes is None
     if sizes is None:

@@ -12,11 +12,11 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 from jax import lax
+from jax.lax import axis_size
 
 from repro.comm.engine import CollectiveEngine
 from repro.comm.topology import ring_perm
 from repro.comm.types import CommunicationType, comm_type
-from repro.compat import axis_size
 
 
 def axis_index(axis: str):

@@ -86,11 +86,11 @@ from typing import Callable, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.lax import axis_size
 
 from repro.comm.overlap import DEFAULT_BUCKET_BYTES, pack_buckets
 from repro.comm.topology import MeshTopology, ring_perm, transpose_perm
 from repro.comm.types import CommunicationType, comm_type
-from repro.compat import axis_size
 
 OPS: Tuple[str, ...] = ("bcast", "all_to_all_tiles", "allreduce",
                         "ring_exchange", "grid_transpose")
@@ -173,13 +173,10 @@ def _set_chunk(stack, k, val):
     return lax.dynamic_update_slice(stack, val[None], (k, 0))
 
 
-def _fused_add(engine, acc, recv):
+def _fused_add(acc, recv):
     if jnp.issubdtype(acc.dtype, jnp.floating):
         from repro.kernels.ring import fused_chunk_add
-        interp = engine.interpret
-        if interp is None:  # auto: compile on TPU, interpret elsewhere
-            interp = jax.default_backend() != "tpu"
-        return fused_chunk_add(acc, recv, interpret=interp)
+        return fused_chunk_add(acc, recv)
     return acc + recv
 
 
@@ -425,7 +422,7 @@ def _allreduce_rs_ag(engine, x, axis):
         recv = _ring_shift(send, axis, +1)
         local = _chunk(stack, (idx - 1 - s) % n)
         stack = _set_chunk(stack, (idx - 1 - s) % n,
-                           _fused_add(engine, local, recv))
+                           _fused_add(local, recv))
 
     # all-gather: circulate the owned chunk around the ring
     cur = _chunk(stack, (idx + 1) % n)
@@ -486,7 +483,7 @@ def _allreduce_int8_ef(engine, x, axis):
         recv = _shift_q(send)
         local = _chunk(stack, (idx - 1 - s) % n)
         stack = _set_chunk(stack, (idx - 1 - s) % n,
-                           _fused_add(engine, local, recv))
+                           _fused_add(local, recv))
 
     # all-gather: quantize the owned chunk (and its residual) once; every
     # rank (owner included) keeps the reconstructed wire value so all ranks
@@ -612,9 +609,6 @@ class CollectiveEngine:
                   applies suite-wide without per-op plumbing.
     ``topology``  optional :class:`MeshTopology` for axis validation, cost-
                   model resolution, and result provenance (``describe()``).
-    ``interpret`` Pallas interpret flag for fused steps; None (default)
-                  resolves to compiled on TPU, interpret elsewhere — the
-                  same rule as :mod:`repro.kernels.ops`.
     ``cost_model`` optional explicit :class:`repro.comm.autotune.CostModel`;
                   None uses the process-wide default (analytic + persisted
                   tuning table).
@@ -622,7 +616,6 @@ class CollectiveEngine:
     comm: CommunicationType = CommunicationType.ICI_DIRECT
     schedule: str = "auto"
     topology: Optional[MeshTopology] = None
-    interpret: Optional[bool] = None
     cost_model: Optional[object] = None
 
     def __post_init__(self):

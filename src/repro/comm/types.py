@@ -60,6 +60,13 @@ TPU_V5E = HardwareModel(
     hbm_bytes=16 * 2**30,
 )
 
+# Published peaks keyed by ``jax.devices()[0].device_kind`` ("TPU v5 lite"
+# is how JAX names a v5e). Source: Google Cloud documentation, "TPU v5e"
+# (197 TFLOP/s bf16, 16 GB HBM at 819 GB/s). A device missing here is an
+# error for any measurement, never a default.
+PEAKS = {"TPU v5 lite": TPU_V5E}
+
+
 # The paper's evaluation hardware, for validating the reproduction's
 # analytical models against the paper's own measurements (Fig. 10, Eq. 4).
 BITTWARE_520N = HardwareModel(

@@ -24,10 +24,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
+from jax import shard_map
 
 from repro.comm.engine import CollectiveEngine
 from repro.comm.types import CommunicationType
-from repro.compat import shard_map
 from repro.core import models
 from repro.core.hpcc import BenchResult, register, timeit
 

@@ -42,11 +42,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
+from jax import shard_map
 
 from repro.comm.callsites import FFT_TRANSPOSE
 from repro.comm.engine import CollectiveEngine
 from repro.comm.types import CommunicationType
-from repro.compat import shard_map
 from repro.core.hpcc import BenchResult, register, timeit
 
 CALLSITE = FFT_TRANSPOSE  # tuning-table tag for both pencil exchanges

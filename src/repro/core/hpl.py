@@ -51,11 +51,11 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
+from jax import shard_map
 
 from repro.comm.callsites import HPL_BLOCK, HPL_PANEL
 from repro.comm.engine import CollectiveEngine
 from repro.comm.types import CommunicationType
-from repro.compat import shard_map
 from repro.core.hpcc import BenchResult, register, timeit
 from repro.core.models import hpl_flops
 from repro.core.ptrans import distribute_cyclic, undistribute_cyclic
@@ -79,12 +79,11 @@ def generate_system(n: int, seed: int = 7) -> Tuple[np.ndarray, np.ndarray, np.n
 
 
 def solve_from_lu(lu: np.ndarray, b: np.ndarray) -> np.ndarray:
-    """Host triangular solves L y = b, U x = y from the packed LU."""
-    import jax.scipy.linalg as jsl
-    l = np.tril(lu, -1) + np.eye(lu.shape[0], dtype=lu.dtype)
-    u = np.triu(lu)
-    y = np.asarray(jsl.solve_triangular(l, b, lower=True, unit_diagonal=True))
-    return np.asarray(jsl.solve_triangular(u, y, lower=False))
+    """Host triangular solves L y = b, U x = y from the packed LU (SciPy on
+    the host CPU; the triangles are read from ``lu`` directly)."""
+    from scipy.linalg import solve_triangular
+    y = solve_triangular(lu, b, lower=True, unit_diagonal=True)
+    return solve_triangular(lu, y, lower=False)
 
 
 def normalized_residual(a: np.ndarray, x: np.ndarray, b: np.ndarray) -> float:
@@ -99,7 +98,7 @@ def normalized_residual(a: np.ndarray, x: np.ndarray, b: np.ndarray) -> float:
 
 
 def _panels(k, diag, row_panel, col_panel, *, pg: int, b: int,
-            engine: CollectiveEngine, interpret, li_global, lj_global):
+            engine: CollectiveEngine, li_global, lj_global):
     """Factor the diagonal block and form + broadcast iteration ``k``'s U/L
     panels (paper Fig. 4 steps 1-4). ``diag``/``row_panel``/``col_panel`` are
     this device's local strips at local block index k // pg, already carrying
@@ -108,18 +107,18 @@ def _panels(k, diag, row_panel, col_panel, *, pg: int, b: int,
     pk = k % pg
 
     # 1. diagonal block (speculative on every device; selected by bcast)
-    lu_local = lu_factor_block(diag, interpret=interpret)
+    lu_local = lu_factor_block(diag)
     lu_blk = engine.bcast(lu_local, "cols", pk, callsite=HPL_BLOCK)
     lu_blk = engine.bcast(lu_blk, "rows", pk, callsite=HPL_BLOCK)
 
     # 2. Top panel: U_kj = L_kk^{-1} A_kj on grid row pk, cols j > k
-    u_panel = trsm_lower_left(lu_blk, row_panel, interpret=interpret)
+    u_panel = trsm_lower_left(lu_blk, row_panel)
     colmask = jnp.repeat(lj_global > k, b)  # (m,)
     u_panel = u_panel * colmask[None, :]
     u_panel = engine.bcast(u_panel, "rows", pk, callsite=HPL_PANEL)
 
     # 3. Left panel: L_ik = A_ik U_kk^{-1} on grid col pk, rows i > k
-    l_panel = trsm_upper_right(lu_blk, col_panel, interpret=interpret)
+    l_panel = trsm_upper_right(lu_blk, col_panel)
     rowmask = jnp.repeat(li_global > k, b)
     l_panel = l_panel * rowmask[:, None]
     l_panel = engine.bcast(l_panel, "cols", pk, callsite=HPL_PANEL)
@@ -127,7 +126,7 @@ def _panels(k, diag, row_panel, col_panel, *, pg: int, b: int,
 
 
 def _update_writeback(k, a, lu_blk, u_panel, l_panel, *, pg: int, b: int,
-                      lb: int, interpret, r, c, li_global, lj_global):
+                      lb: int, r, c, li_global, lj_global):
     """Apply iteration ``k``'s trailing rank-b GEMM over the full local
     matrix and write back the factored panels."""
     m = lb * b
@@ -137,7 +136,7 @@ def _update_writeback(k, a, lu_blk, u_panel, l_panel, *, pg: int, b: int,
     rowmask = jnp.repeat(li_global > k, b)
 
     # 4. trailing update: masks zero the factored rows/cols
-    a = gemm_update(a, l_panel, u_panel, alpha=-1.0, interpret=interpret)
+    a = gemm_update(a, l_panel, u_panel, alpha=-1.0, bm=b, bn=b)
 
     # 5. write back factored panels. The rank masks are folded INTO the
     # update values so every write is one slice-sized dynamic-update-slice —
@@ -157,7 +156,7 @@ def _update_writeback(k, a, lu_blk, u_panel, l_panel, *, pg: int, b: int,
 
 
 def _iteration(k, a, *, pg: int, b: int, lb: int, engine: CollectiveEngine,
-               interpret, r, c, li_global, lj_global):
+               r, c, li_global, lj_global):
     """Eager iteration: factor+broadcast panels for k, then update."""
     m = lb * b
     lk = k // pg
@@ -166,14 +165,14 @@ def _iteration(k, a, *, pg: int, b: int, lb: int, engine: CollectiveEngine,
     col_panel = lax.dynamic_slice(a, (0, lk * b), (m, b))
     lu_blk, u_panel, l_panel = _panels(
         k, diag, row_panel, col_panel, pg=pg, b=b, engine=engine,
-        interpret=interpret, li_global=li_global, lj_global=lj_global)
+        li_global=li_global, lj_global=lj_global)
     return _update_writeback(k, a, lu_blk, u_panel, l_panel, pg=pg, b=b,
-                             lb=lb, interpret=interpret, r=r, c=c,
-                             li_global=li_global, lj_global=lj_global)
+                             lb=lb, r=r, c=c, li_global=li_global,
+                             lj_global=lj_global)
 
 
 def _strip_panels(kidx, a, flight, *, pg: int, b: int, lb: int,
-                  engine: CollectiveEngine, interpret, li_global, lj_global):
+                  engine: CollectiveEngine, li_global, lj_global):
     """Form + broadcast iteration ``kidx``'s panels from thin strips of
     ``a``, first applying every pending in-flight update (the panel sets in
     ``flight``, oldest first) *restricted to the band* ``kidx`` reads — 2
@@ -188,19 +187,18 @@ def _strip_panels(kidx, a, flight, *, pg: int, b: int, lb: int,
     for lu_blk, u_panel, l_panel in flight:
         l_rows = lax.dynamic_slice(l_panel, (lk * b, 0), (b, b))
         row_strip = gemm_update(row_strip, l_rows, u_panel, alpha=-1.0,
-                                interpret=interpret)
+                                bm=b, bn=b)
         u_cols = lax.dynamic_slice(u_panel, (0, lk * b), (b, b))
         col_strip = gemm_update(col_strip, l_panel, u_cols, alpha=-1.0,
-                                interpret=interpret)
+                                bm=b, bn=b)
     diag = lax.dynamic_slice(col_strip, (lk * b, 0), (b, b))
     return _panels(kidx, diag, row_strip, col_strip, pg=pg, b=b,
-                   engine=engine, interpret=interpret, li_global=li_global,
-                   lj_global=lj_global)
+                   engine=engine, li_global=li_global, lj_global=lj_global)
 
 
 def _iteration_lookahead(k, carry, *, pg: int, nb: int, b: int, lb: int,
-                         depth: int, engine: CollectiveEngine, interpret,
-                         r, c, li_global, lj_global):
+                         depth: int, engine: CollectiveEngine, r, c,
+                         li_global, lj_global):
     """Depth-d lookahead iteration (paper Fig. 5/7): the carry holds the
     ``depth`` in-flight panel sets for iterations k..k+d-1, already
     broadcast. Update only the strips iteration k+d reads (applying the d
@@ -226,13 +224,11 @@ def _iteration_lookahead(k, carry, *, pg: int, nb: int, b: int, lb: int,
     # 1.-2. thin strip updates for the k+d band, then issue k+d's
     # factorization and row/column broadcasts now
     nxt = _strip_panels(kd, a, flight, pg=pg, b=b, lb=lb, engine=engine,
-                        interpret=interpret, li_global=li_global,
-                        lj_global=lj_global)
+                        li_global=li_global, lj_global=lj_global)
 
     # 3. bulk trailing update + write back iteration k's factored panels
     # (the oldest in-flight set)
-    a = _update_writeback(k, a, *flight[0], pg=pg, b=b, lb=lb,
-                          interpret=interpret, r=r, c=c,
+    a = _update_writeback(k, a, *flight[0], pg=pg, b=b, lb=lb, r=r, c=c,
                           li_global=li_global, lj_global=lj_global)
     return (a,) + flight[1:] + (nxt,)
 
@@ -252,14 +248,14 @@ def lookahead_depth(lookahead) -> int:
 
 
 def _hpl_body(a_loc, *, pg: int, nb: int, b: int, engine: CollectiveEngine,
-              interpret: bool, lookahead=False):
+              lookahead=False):
     a = a_loc[0]
     lb = nb // pg
     r = lax.axis_index("rows")
     c = lax.axis_index("cols")
     li_global = jnp.arange(lb) * pg + r
     lj_global = jnp.arange(lb) * pg + c
-    strip_kw = dict(pg=pg, b=b, lb=lb, engine=engine, interpret=interpret,
+    strip_kw = dict(pg=pg, b=b, lb=lb, engine=engine,
                     li_global=li_global, lj_global=lj_global)
     common = dict(r=r, c=c, **strip_kw)
     # no point carrying more panel sets than there are iterations
@@ -282,16 +278,14 @@ def _hpl_body(a_loc, *, pg: int, nb: int, b: int, engine: CollectiveEngine,
 
 def make_factorize(mesh, *, pg: int, nb: int, b: int,
                    comm=CommunicationType.ICI_DIRECT, schedule: str = "auto",
-                   interpret: bool = True, lookahead=False,
-                   engine: CollectiveEngine = None):
+                   lookahead=False, engine: CollectiveEngine = None):
     """``lookahead`` is a pipeline depth: False/0 eager, True/1 one panel
     set in flight, d >= 2 the depth-d pipeline."""
-    engine = engine or CollectiveEngine.for_mesh(mesh, comm, schedule,
-                                                 interpret=interpret)
+    engine = engine or CollectiveEngine.for_mesh(mesh, comm, schedule)
     spec = P(("rows", "cols"), None, None)
     fn = shard_map(
         partial(_hpl_body, pg=pg, nb=nb, b=b, engine=engine,
-                interpret=interpret, lookahead=lookahead),
+                lookahead=lookahead),
         mesh=mesh, in_specs=(spec,), out_specs=spec, check_vma=False)
     return jax.jit(fn)
 
@@ -299,8 +293,7 @@ def make_factorize(mesh, *, pg: int, nb: int, b: int,
 @register("hpl")
 def run_hpl(mesh, comm=CommunicationType.ICI_DIRECT, *, n: int = 512,
             b: int = 64, schedule: str = "auto", reps: int = 2,
-            interpret: bool = True, validate: bool = True,
-            lookahead=False) -> BenchResult:
+            validate: bool = True, lookahead=False) -> BenchResult:
     """mesh axes ('rows', 'cols'), P = Q (paper's quadratic torus).
 
     ``lookahead`` runs the overlapped factorization (paper Fig. 5/7):
@@ -313,8 +306,7 @@ def run_hpl(mesh, comm=CommunicationType.ICI_DIRECT, *, n: int = 512,
     assert mesh.shape["cols"] == pg, "paper requires a quadratic torus"
     nb = n // b
     assert nb % pg == 0, (n, b, pg)
-    engine = CollectiveEngine.for_mesh(mesh, comm, schedule,
-                                       interpret=interpret)
+    engine = CollectiveEngine.for_mesh(mesh, comm, schedule)
 
     m = (nb // pg) * b
     if lookahead == "auto":
@@ -334,7 +326,7 @@ def run_hpl(mesh, comm=CommunicationType.ICI_DIRECT, *, n: int = 512,
     a_sh = jax.device_put(distribute_cyclic(a, pg, b), spec)
 
     fact = make_factorize(mesh, pg=pg, nb=nb, b=b, engine=engine,
-                          interpret=interpret, lookahead=depth)
+                          lookahead=depth)
     out, t = timeit(fact, a_sh, reps=reps)
 
     err = 0.0

@@ -24,33 +24,33 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.compat import make_mesh
+from repro.launch.mesh import make_mesh
 from repro.core.hpcc import BenchResult, register, timeit
 from repro.core.hpl import (generate_system, make_factorize,
                             normalized_residual, solve_from_lu)
 from repro.core.models import hpl_flops
 
 
-def lu_blocked(a: jnp.ndarray, b: int, *, interpret: bool = True) -> jnp.ndarray:
+def lu_blocked(a: jnp.ndarray, b: int) -> jnp.ndarray:
     """Blocked LU of (n, n) ``a`` with block size ``b``; returns packed
     L\\U. Thin wrapper: the distributed right-looking factorization on a
     1 x 1 grid (every broadcast is an identity, the trailing update's
     row/column masks reproduce the shrinking-submatrix sweep)."""
     n = a.shape[0]
     mesh = make_mesh((1, 1), ("rows", "cols"))
-    fact = make_factorize(mesh, pg=1, nb=n // b, b=b, interpret=interpret)
+    fact = make_factorize(mesh, pg=1, nb=n // b, b=b)
     return fact(a[None])[0]
 
 
 @register("hpl_single")
 def run_hpl_single(mesh=None, comm=None, *, n: int = 512, b: int = 64,
-                   reps: int = 2, interpret: bool = True,
-                   validate: bool = True, schedule: str = "auto") -> BenchResult:
+                   reps: int = 2, validate: bool = True,
+                   schedule: str = "auto") -> BenchResult:
     # single device: no communication — ``schedule`` is accepted so the
     # drivers can pass one flag suite-wide; recorded as "local" in results.
     a, x_true, b_vec = generate_system(n)
     a_dev = jnp.asarray(a)
-    fn = jax.jit(partial(lu_blocked, b=b, interpret=interpret))
+    fn = jax.jit(partial(lu_blocked, b=b))
     out, t = timeit(fn, a_dev, reps=reps)
 
     err = 0.0

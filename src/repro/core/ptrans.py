@@ -41,11 +41,11 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
+from jax import shard_map
 
 from repro.comm.callsites import PTRANS_EXCHANGE
 from repro.comm.engine import CollectiveEngine
 from repro.comm.types import CommunicationType
-from repro.compat import shard_map
 from repro.core.hpcc import BenchResult, register, timeit
 from repro.kernels.ops import transpose_add
 
@@ -96,12 +96,12 @@ CALLSITE = PTRANS_EXCHANGE  # tuning-table tag for the partner exchange
 
 
 def _ptrans_body(a_loc, b_loc, *, pg: int, engine: CollectiveEngine,
-                 interpret: bool, nchunks: int = 1):
+                 nchunks: int = 1):
     a_loc, b_loc = a_loc[0], b_loc[0]
     if nchunks <= 1:
         recv = engine.grid_transpose(a_loc, ("rows", "cols"), pg,
                                      callsite=CALLSITE)
-        out = transpose_add(recv, b_loc, interpret=interpret)
+        out = transpose_add(recv, b_loc)
         return out[None]
 
     # strip-wise pipeline: row strip i of A lands, its transpose-add writes
@@ -109,7 +109,7 @@ def _ptrans_body(a_loc, b_loc, *, pg: int, engine: CollectiveEngine,
     def consume(strip, start):
         b_cols = lax.slice_in_dim(b_loc, start, start + strip.shape[0],
                                   axis=1)
-        return transpose_add(strip, b_cols, interpret=interpret)
+        return transpose_add(strip, b_cols)
 
     out = engine.pipelined("grid_transpose", a_loc, ("rows", "cols"),
                            pg=pg, nchunks=nchunks, split_axis=0,
@@ -118,20 +118,18 @@ def _ptrans_body(a_loc, b_loc, *, pg: int, engine: CollectiveEngine,
     return out[None]
 
 
-def make_step(mesh, pg: int, engine: CollectiveEngine, interpret: bool = True,
-              nchunks: int = 1):
+def make_step(mesh, pg: int, engine: CollectiveEngine, nchunks: int = 1):
     spec = P(("rows", "cols"), None, None)
     fn = shard_map(
-        partial(_ptrans_body, pg=pg, engine=engine, interpret=interpret,
-                nchunks=nchunks),
+        partial(_ptrans_body, pg=pg, engine=engine, nchunks=nchunks),
         mesh=mesh, in_specs=(spec, spec), out_specs=spec, check_vma=False)
     return jax.jit(fn)
 
 
 @register("ptrans")
 def run_ptrans(mesh, comm=CommunicationType.ICI_DIRECT, *, n: int = 1024,
-               b: int = 128, reps: int = 3, interpret: bool = True,
-               validate: bool = True, schedule: str = "auto",
+               b: int = 128, reps: int = 3, validate: bool = True,
+               schedule: str = "auto",
                nchunks="auto") -> BenchResult:
     """mesh must have axes ('rows', 'cols') with equal sizes (P = Q).
 
@@ -140,8 +138,7 @@ def run_ptrans(mesh, comm=CommunicationType.ICI_DIRECT, *, n: int = 1024,
     fill-cost model. Bit-identical output for every value."""
     pg = mesh.shape["rows"]
     assert mesh.shape["cols"] == pg, "paper requires P = Q"
-    engine = CollectiveEngine.for_mesh(mesh, comm, schedule,
-                                       interpret=interpret)
+    engine = CollectiveEngine.for_mesh(mesh, comm, schedule)
     rng = np.random.default_rng(42)
     a = rng.standard_normal((n, n), dtype=np.float32)
     bm = rng.standard_normal((n, n), dtype=np.float32)
@@ -159,7 +156,7 @@ def run_ptrans(mesh, comm=CommunicationType.ICI_DIRECT, *, n: int = 1024,
     a_sh = jax.device_put(distribute_cyclic(a, pg, b), spec)
     b_sh = jax.device_put(distribute_cyclic(bm, pg, b), spec)
 
-    step = make_step(mesh, pg, engine, interpret, nchunks=nchunks)
+    step = make_step(mesh, pg, engine, nchunks=nchunks)
     out, t = timeit(step, a_sh, b_sh, reps=reps)
 
     err = 0.0

@@ -37,11 +37,11 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
+from jax import shard_map
 
 from repro.comm.callsites import RA_UPDATES
 from repro.comm.engine import CollectiveEngine
 from repro.comm.types import CommunicationType
-from repro.compat import shard_map
 from repro.core.hpcc import BenchResult, register, timeit
 
 # 32-bit variant of the HPCC LCG (JAX default disables x64; the generator is

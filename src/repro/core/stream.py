@@ -13,17 +13,16 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
+from jax import shard_map
 
 from repro.comm.types import CommunicationType
-from repro.compat import shard_map
 from repro.core.hpcc import BenchResult, register, timeit
-from repro.kernels import stream as sk
+from repro.kernels import ops
 
 
 @register("stream")
 def run_stream(mesh, comm=CommunicationType.ICI_DIRECT, *,
-               elems_per_device: int = 1 << 20, reps: int = 3,
-               interpret: bool = True) -> BenchResult:
+               elems_per_device: int = 1 << 20, reps: int = 3) -> BenchResult:
     n_dev = mesh.devices.size
     n = elems_per_device * n_dev
     spec = NamedSharding(mesh, P("x"))
@@ -36,10 +35,10 @@ def run_stream(mesh, comm=CommunicationType.ICI_DIRECT, *,
         fn, mesh=mesh, in_specs=(P("x"),) * n_in, out_specs=P("x"),
         check_vma=False))
 
-    copy = smap(lambda x: sk.stream_copy(x, interpret=interpret), 1)
-    scale = smap(lambda x: sk.stream_scale(x, alpha, interpret=interpret), 1)
-    add = smap(lambda x, y: sk.stream_add(x, y, interpret=interpret), 2)
-    triad = smap(lambda x, y: sk.stream_triad(x, y, alpha, interpret=interpret), 2)
+    copy = smap(ops.stream_copy, 1)
+    scale = smap(lambda x: ops.stream_scale(x, alpha), 1)
+    add = smap(ops.stream_add, 2)
+    triad = smap(lambda x, y: ops.stream_triad(x, y, alpha), 2)
 
     times = {}
     bw = {}

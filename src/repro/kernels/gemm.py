@@ -17,16 +17,43 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+# the TPU's (8, 128) float32 tile: a compiled kernel's block dims are
+# multiples of these or span the whole array dim
+SUBLANE, LANE = 8, 128
 
-def fit_block(size: int, pref: int) -> int:
-    """Largest divisor of ``size`` that is <= pref (block shapes must tile)."""
-    b = min(pref, size)
-    while size % b:
-        b -= 1
-    return b
+
+def fit_block(size: int, pref: int, align: int = 1) -> int:
+    """Block for one array dim: the whole dim when it is <= ``pref``, else
+    the largest divisor of ``size`` that is <= ``pref`` and a multiple of
+    ``align`` (block shapes must tile; compiled kernels pass the TPU tile as
+    ``align``). Raises ValueError when no such divisor exists."""
+    if size <= pref:
+        return size
+    b = pref - pref % align
+    while b >= align:
+        if size % b == 0:
+            return b
+        b -= align
+    raise ValueError(f"no block <= {pref} divides {size} in multiples of "
+                     f"{align}")
+
+
+def _dot(a, b):
+    """fp32 accumulation; float32 operands at full float32 precision (the
+    MXU's multi-pass mode) — the HPL residual and the GEMM check depend on
+    it, and Mosaic's default for float32 is not specified."""
+    prec = lax.Precision.HIGHEST if a.dtype == jnp.float32 else None
+    return jnp.dot(a, b, precision=prec, preferred_element_type=jnp.float32)
+
+
+def _blocks(M, N, K, bm, bn, bk, interpret):
+    sub, lane = (1, 1) if interpret else (SUBLANE, LANE)
+    return (fit_block(M, bm, sub), fit_block(N, bn, lane),
+            fit_block(K, bk, lane))
 
 
 def _matmul_kernel(a_ref, b_ref, o_ref, acc_ref, *, nk: int):
@@ -34,8 +61,7 @@ def _matmul_kernel(a_ref, b_ref, o_ref, acc_ref, *, nk: int):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += jnp.dot(a_ref[...], b_ref[...],
-                            preferred_element_type=jnp.float32)
+    acc_ref[...] += _dot(a_ref[...], b_ref[...])
 
     @pl.when(pl.program_id(2) == nk - 1)
     def _store():
@@ -48,7 +74,7 @@ def matmul(a: jnp.ndarray, b: jnp.ndarray, *, bm: int = 256, bn: int = 256,
     M, K = a.shape
     K2, N = b.shape
     assert K == K2, (a.shape, b.shape)
-    bm, bn, bk = fit_block(M, bm), fit_block(N, bn), fit_block(K, bk)
+    bm, bn, bk = _blocks(M, N, K, bm, bn, bk, interpret)
     out_dtype = out_dtype or a.dtype
     grid = (M // bm, N // bn, K // bk)
     return pl.pallas_call(
@@ -71,8 +97,7 @@ def _gemm_update_kernel(c_ref, a_ref, b_ref, o_ref, acc_ref, *, nk: int,
     def _init():
         acc_ref[...] = c_ref[...].astype(jnp.float32)
 
-    acc_ref[...] += alpha * jnp.dot(a_ref[...], b_ref[...],
-                                    preferred_element_type=jnp.float32)
+    acc_ref[...] += alpha * _dot(a_ref[...], b_ref[...])
 
     @pl.when(pl.program_id(2) == nk - 1)
     def _store():
@@ -90,7 +115,7 @@ def gemm_update(c: jnp.ndarray, a: jnp.ndarray, b: jnp.ndarray, *,
     M, K = a.shape
     _, N = b.shape
     assert c.shape == (M, N)
-    bm, bn, bk = fit_block(M, bm), fit_block(N, bn), fit_block(K, bk)
+    bm, bn, bk = _blocks(M, N, K, bm, bn, bk, interpret)
     grid = (M // bm, N // bn, K // bk)
     # aliasing is the TPU in-place path; interpret mode implements donation
     # with a defensive whole-buffer copy per grid step (measured, §Perf C3)

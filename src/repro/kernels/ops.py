@@ -1,8 +1,11 @@
 """jit'd public wrappers for the Pallas kernels.
 
-``interpret`` defaults to True off-TPU (this container is CPU-only; the
-kernel bodies then execute as the Python/jnp semantics of the same BlockSpec
-pipeline, which is the validation mode the assignment prescribes).
+One rule decides how every kernel runs (:func:`interpret_mode`): compiled
+by Mosaic on a TPU backend, and in Pallas interpret mode on any other —
+there the kernel bodies execute as the jnp semantics of the same BlockSpec
+pipeline, which is how the CPU test suite validates them. Code that must
+compile a kernel for a TPU it does not run on (tests/test_tpu_compile.py)
+calls the kernel modules directly with ``interpret=False``.
 """
 from __future__ import annotations
 
@@ -18,70 +21,66 @@ from repro.kernels import stream as _stream
 from repro.kernels import transpose as _transpose
 
 
-def on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def interpret_mode() -> bool:
+    """True unless the default backend is a TPU."""
+    return jax.default_backend() != "tpu"
 
 
-def _interp(flag):
-    return (not on_tpu()) if flag is None else flag
-
-
-@partial(jax.jit, static_argnames=("bm", "bn", "bk", "out_dtype", "interpret"))
-def matmul(a, b, *, bm=256, bn=256, bk=256, out_dtype=None, interpret=None):
+@partial(jax.jit, static_argnames=("bm", "bn", "bk", "out_dtype"))
+def matmul(a, b, *, bm=256, bn=256, bk=256, out_dtype=None):
     return _gemm.matmul(a, b, bm=bm, bn=bn, bk=bk, out_dtype=out_dtype,
-                        interpret=_interp(interpret))
+                        interpret=interpret_mode())
 
 
-@partial(jax.jit, static_argnames=("alpha", "bm", "bn", "bk", "interpret"),
+@partial(jax.jit, static_argnames=("alpha", "bm", "bn", "bk"),
          donate_argnums=(0,))
-def gemm_update(c, a, b, *, alpha=-1.0, bm=256, bn=256, bk=256, interpret=None):
+def gemm_update(c, a, b, *, alpha=-1.0, bm=256, bn=256, bk=256):
     return _gemm.gemm_update(c, a, b, alpha=alpha, bm=bm, bn=bn, bk=bk,
-                             interpret=_interp(interpret))
+                             interpret=interpret_mode())
 
 
-@partial(jax.jit, static_argnames=("block", "interpret"))
-def transpose_add(a, b, *, block=256, interpret=None):
+@partial(jax.jit, static_argnames=("block",))
+def transpose_add(a, b, *, block=256):
     return _transpose.transpose_add(a, b, block=block,
-                                    interpret=_interp(interpret))
+                                    interpret=interpret_mode())
 
 
-@partial(jax.jit, static_argnames=("interpret",))
-def lu_factor_block(a, *, interpret=None):
-    return _lu.lu_factor_block(a, interpret=_interp(interpret))
+@jax.jit
+def lu_factor_block(a):
+    return _lu.lu_factor_block(a, interpret=interpret_mode())
 
 
-@partial(jax.jit, static_argnames=("bn", "interpret"))
-def trsm_lower_left(lu, b, *, bn=256, interpret=None):
-    return _lu.trsm_lower_left(lu, b, bn=bn, interpret=_interp(interpret))
+@partial(jax.jit, static_argnames=("bn",))
+def trsm_lower_left(lu, b, *, bn=256):
+    return _lu.trsm_lower_left(lu, b, bn=bn, interpret=interpret_mode())
 
 
-@partial(jax.jit, static_argnames=("bm", "interpret"))
-def trsm_upper_right(lu, b, *, bm=256, interpret=None):
-    return _lu.trsm_upper_right(lu, b, bm=bm, interpret=_interp(interpret))
+@partial(jax.jit, static_argnames=("bm",))
+def trsm_upper_right(lu, b, *, bm=256):
+    return _lu.trsm_upper_right(lu, b, bm=bm, interpret=interpret_mode())
 
 
-@partial(jax.jit, static_argnames=("causal", "q_offset", "bq", "bk", "interpret"))
-def flash_attention(q, k, v, *, causal=True, q_offset=0, bq=512, bk=512,
-                    interpret=None):
+@partial(jax.jit, static_argnames=("causal", "q_offset", "bq", "bk"))
+def flash_attention(q, k, v, *, causal=True, q_offset=0, bq=512, bk=512):
     return _attn.flash_attention(q, k, v, causal=causal, q_offset=q_offset,
-                                 bq=bq, bk=bk, interpret=_interp(interpret))
+                                 bq=bq, bk=bk, interpret=interpret_mode())
 
 
-@partial(jax.jit, static_argnames=("interpret",))
-def stream_copy(a, *, interpret=None):
-    return _stream.stream_copy(a, interpret=_interp(interpret))
+@jax.jit
+def stream_copy(a):
+    return _stream.stream_copy(a, interpret=interpret_mode())
 
 
-@partial(jax.jit, static_argnames=("alpha", "interpret"))
-def stream_scale(c, alpha, *, interpret=None):
-    return _stream.stream_scale(c, alpha, interpret=_interp(interpret))
+@partial(jax.jit, static_argnames=("alpha",))
+def stream_scale(c, alpha):
+    return _stream.stream_scale(c, alpha, interpret=interpret_mode())
 
 
-@partial(jax.jit, static_argnames=("interpret",))
-def stream_add(a, b, *, interpret=None):
-    return _stream.stream_add(a, b, interpret=_interp(interpret))
+@jax.jit
+def stream_add(a, b):
+    return _stream.stream_add(a, b, interpret=interpret_mode())
 
 
-@partial(jax.jit, static_argnames=("alpha", "interpret"))
-def stream_triad(b, c, alpha, *, interpret=None):
-    return _stream.stream_triad(b, c, alpha, interpret=_interp(interpret))
+@partial(jax.jit, static_argnames=("alpha",))
+def stream_triad(b, c, alpha):
+    return _stream.stream_triad(b, c, alpha, interpret=interpret_mode())

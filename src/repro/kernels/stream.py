@@ -14,12 +14,12 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-LANES = 128
+from repro.kernels.gemm import LANE, SUBLANE, fit_block
 
 
 def _as2d(x):
-    assert x.size % LANES == 0, x.shape
-    return x.reshape(-1, LANES)
+    assert x.size % LANE == 0, x.shape
+    return x.reshape(-1, LANE)
 
 
 def _copy_kernel(a_ref, o_ref):
@@ -43,10 +43,9 @@ def _triad_kernel(b_ref, c_ref, o_ref, *, alpha):
 def _run(kernel, args, out_dtype, *, block_rows=2048, interpret=False):
     x0 = _as2d(args[0])
     rows = x0.shape[0]
-    br = min(block_rows, rows)
-    assert rows % br == 0
+    br = fit_block(rows, block_rows, 1 if interpret else SUBLANE)
     grid = (rows // br,)
-    spec = pl.BlockSpec((br, LANES), lambda i: (i, 0))
+    spec = pl.BlockSpec((br, LANE), lambda i: (i, 0))
     out = pl.pallas_call(
         kernel,
         grid=grid,

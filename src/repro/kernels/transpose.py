@@ -12,9 +12,13 @@ is what the PTRANS roofline records.
 """
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels.gemm import LANE, fit_block
 
 
 def _transpose_add_kernel(a_ref, b_ref, o_ref):
@@ -25,12 +29,14 @@ def _transpose_add_kernel(a_ref, b_ref, o_ref):
 def transpose_add(a: jnp.ndarray, b: jnp.ndarray, *, block: int = 256,
                   interpret: bool = False) -> jnp.ndarray:
     """C = B + A^T for square-tileable matrices. a: (M, N), b/C: (N, M)."""
-    from repro.kernels.gemm import fit_block
     M, N = a.shape
     assert b.shape == (N, M)
-    bs = fit_block(M, fit_block(N, block))
-    while M % bs or N % bs:
-        bs -= 1
+    # square tiles (the A tile is read transposed): a common divisor, which
+    # a compiled kernel needs lane-aligned unless it spans both dims
+    bs = fit_block(math.gcd(M, N), block, 1 if interpret else LANE)
+    if not (interpret or bs % LANE == 0 or bs == M == N):
+        raise ValueError(f"transpose_add: no lane-aligned square tile for "
+                         f"{a.shape}")
     grid = (N // bs, M // bs)  # output tile (i, j) of C
     return pl.pallas_call(
         _transpose_add_kernel,

@@ -4,8 +4,7 @@ from __future__ import annotations
 
 import jax
 import numpy as np
-
-from repro.compat import make_mesh as _make_mesh
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -14,29 +13,35 @@ def make_production_mesh(*, multi_pod: bool = False):
     domain — the paper's PCIe+MPI network)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
-def make_mesh(shape, axes):
-    return _make_mesh(shape, axes)
+def make_mesh(shape, axes, *, devices=None):
+    """``jax.make_mesh`` with Auto axes: the repo's jit paths place arrays
+    through sharding annotations (GSPMD), which the Explicit axes that
+    ``jax.make_mesh`` defaults to would turn into sharding-in-types."""
+    axes = tuple(axes)
+    return jax.make_mesh(tuple(shape), axes,
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_local_mesh(axes=("data", "model")):
     """Best-effort mesh over however many local devices exist (tests/benches)."""
     n = len(jax.devices())
     if len(axes) == 1:
-        return _make_mesh((n,), tuple(axes))
+        return make_mesh((n,), tuple(axes))
     # squarest 2-way factorization
     a = int(np.floor(np.sqrt(n)))
     while n % a:
         a -= 1
-    return _make_mesh((n // a, a), tuple(axes))
+    return make_mesh((n // a, a), tuple(axes))
 
 
 def make_ring_mesh(name: str = "x"):
     n = len(jax.devices())
-    return _make_mesh((n,), (name,))
+    return make_mesh((n,), (name,))
 
 
 def make_torus_mesh(pg: int, names=("rows", "cols")):
-    return _make_mesh((pg, pg), tuple(names))
+    return make_mesh((pg, pg), tuple(names))

@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.configs import get_config, list_archs, reduced
 from repro.models.model import build_model
 from repro.train.serve import generate
@@ -56,7 +57,7 @@ def _legacy(model, params, cfg, args):
 
 
 def _paged(model, params, cfg, args):
-    from repro.compat import make_mesh
+    from repro.launch.mesh import make_mesh
     from repro.launch.train import parse_fault_args
     from repro.models.kvcache import PagedCacheConfig
     from repro.serve import ServeEngine
@@ -154,6 +155,7 @@ def main():
                     help="whole-batch generate loop instead of the "
                          "continuous-batching engine")
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = reduced(get_config(args.arch))
     model = build_model(cfg)

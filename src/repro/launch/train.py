@@ -13,6 +13,7 @@ import logging
 
 import jax
 
+from repro.compile_cache import use_compile_cache
 from repro.configs import RunConfig, get_config, list_archs, reduced
 from repro.data import DataConfig
 from repro.launch.mesh import make_local_mesh
@@ -74,6 +75,7 @@ def main():
 
     logging.basicConfig(level=logging.INFO,
                         format="%(asctime)s %(name)s %(message)s")
+    use_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

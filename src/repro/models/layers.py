@@ -206,7 +206,7 @@ def _flash_sharded(q, k, v, *, shard, causal: bool):
     materialization that dominates every prefill cell's HBM term (§Perf
     iteration A2). Returns None when this sharding is not applicable."""
     from jax.sharding import PartitionSpec as P
-    from repro.compat import shard_map
+    from jax import shard_map
     from repro.kernels import ops as kops
 
     mesh = getattr(shard, "mesh", None)

@@ -33,10 +33,10 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
+from jax import shard_map
 
 from repro.comm.callsites import MOE_COMBINE, MOE_DISPATCH
 from repro.comm.engine import CollectiveEngine
-from repro.compat import shard_map
 from repro.configs.base import ModelConfig
 
 # tuning-table callsite tags for the two expert exchanges (from the central

@@ -100,7 +100,10 @@ def ssd_chunked(x, dt, A, B, C, chunk: int, h0=None):
     # decay L[b,c,h,q,k] = exp(cs_q - cs_k) for q >= k
     diff = cs[:, :, :, None, :] - cs[:, :, None, :, :]  # (b,nc,Q,Q,H) q,k
     mask = jnp.tril(jnp.ones((Q, Q), bool))
-    decay = jnp.where(mask[None, None, :, :, None], jnp.exp(diff), 0.0)
+    # mask before the exp: above the diagonal diff > 0 grows with the chunk
+    # and overflows, and exp's gradient there (inf) times where's zero
+    # cotangent is NaN
+    decay = jnp.exp(jnp.where(mask[None, None, :, :, None], diff, -jnp.inf))
     # fold group->head: M[b,c,h,q,k]
     scores_h = jnp.repeat(scores, rep, axis=2) if rep > 1 else scores
     # scores_h: (b,nc,G*rep=H,q,k); decay: (b,nc,q,k,H) -> align

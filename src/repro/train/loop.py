@@ -129,8 +129,15 @@ def train_loop(model_cfg: ModelConfig, run_cfg: RunConfig, data_cfg: DataConfig,
     else:
         if mesh is not None and start_step == 0:
             state = shard_state(state, mesh, zero1=loop_cfg.zero1)
-        step_fn = make_train_step(model, run_cfg, mesh or jax.sharding.Mesh(
-            np.array(jax.devices()[:1]), ("x",)), total_steps=loop_cfg.steps)
+        step_mesh = mesh or jax.sharding.Mesh(np.array(jax.devices()[:1]),
+                                              ("x",))
+        if mesh is None:
+            # give step 0 the placement the step returns, or the second call
+            # sees new input shardings and compiles the step again
+            state = jax.device_put(state, jax.NamedSharding(
+                step_mesh, jax.sharding.PartitionSpec()))
+        step_fn = make_train_step(model, run_cfg, step_mesh,
+                                  total_steps=loop_cfg.steps)
 
     monitor = StragglerMonitor(deadline_factor=run_cfg.step_deadline_factor,
                                policy=loop_cfg.straggler_policy)
@@ -255,7 +262,7 @@ def train_loop_elastic(model_cfg: ModelConfig, run_cfg: RunConfig,
         if not survivors:
             raise RuntimeError("every rank lost; nothing to resume on") from e
         n = largest_divisible(len(survivors), data_cfg.global_batch)
-        from repro.compat import make_mesh
+        from repro.launch.mesh import make_mesh
         new_mesh = make_mesh((n,), tuple(mesh.axis_names),
                              devices=np.array(survivors[:n]))
         log.warning("rank(s) %s lost at step %d; resuming on %d survivors",

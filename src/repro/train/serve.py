@@ -22,11 +22,11 @@ from typing import Callable, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax import shard_map
 
 from repro import sharding as sh
 from repro.comm.callsites import DECODE_MOE
 from repro.comm.engine import CollectiveEngine
-from repro.compat import shard_map
 from repro.models.model import Model
 
 

@@ -26,13 +26,13 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax import shard_map
 
 from repro import sharding as sh
 from repro.comm import compression
 from repro.comm.callsites import DP_GRADS
 from repro.comm.engine import CollectiveEngine
 from repro.comm.types import CommunicationType, comm_type
-from repro.compat import shard_map
 from repro.configs.base import ModelConfig, RunConfig
 from repro.models import moe as MOE
 from repro.models.model import Model, next_token_loss

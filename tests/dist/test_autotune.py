@@ -17,10 +17,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
+from jax import shard_map
 
 from repro.comm.autotune import CostModel, TuningTable, autotune_mesh
 from repro.comm.engine import CollectiveEngine, schedules_for
-from repro.compat import make_mesh, shard_map
+from repro.launch.mesh import make_mesh
 
 NDEV = 8
 pytestmark = pytest.mark.skipif(

@@ -16,7 +16,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.comm.callsites import FFT_TRANSPOSE, RA_UPDATES
 from repro.comm.engine import CollectiveEngine, schedules_for
-from repro.compat import make_mesh
+from repro.launch.mesh import make_mesh
 from repro.core import fft as FFT
 from repro.core import randomaccess as RA
 

@@ -18,7 +18,7 @@ import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.comm.engine import CollectiveEngine, schedules_for
-from repro.compat import make_mesh
+from repro.launch.mesh import make_mesh
 from repro.configs import get_config, reduced
 from repro.models import moe as MOE
 
@@ -159,7 +159,7 @@ def test_experts_must_divide_over_axis(ring):
 def test_pipelined_exchange_bit_identical_to_monolithic(ring, schedule):
     """exchange_dispatch/combine pipelined into capacity strips move exactly
     the same bytes as the monolithic exchange, for every schedule."""
-    from repro.compat import shard_map
+    from jax import shard_map
     rng = np.random.default_rng(9)
     buf = rng.integers(-8, 8, (NDEV, 2, 2 * NDEV, 5, 4)).astype(np.float32)
     eng = CollectiveEngine.for_mesh(ring, schedule=schedule)

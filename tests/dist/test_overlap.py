@@ -18,9 +18,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
+from jax import shard_map
 
 from repro.comm.engine import CollectiveEngine, schedules_for
-from repro.compat import make_mesh, shard_map
+from repro.launch.mesh import make_mesh
 
 NDEV = 8
 pytestmark = pytest.mark.skipif(

@@ -22,6 +22,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
+from jax import shard_map
 
 from repro.comm.autotune import CostModel, _seg_time, route_links, segments
 from repro.comm.callsites import HPL_PANEL
@@ -29,7 +30,7 @@ from repro.comm.engine import CollectiveEngine, schedules_for
 from repro.comm.faults import FaultInjector, FaultSchedule
 from repro.comm.retune import RetuneController, Watched
 from repro.comm.types import TPU_V5E
-from repro.compat import make_mesh, shard_map
+from repro.launch.mesh import make_mesh
 from repro.configs import RunConfig
 from repro.configs.qwen3_moe_235b_a22b import tiny
 from repro.data import DataConfig

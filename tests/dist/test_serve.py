@@ -17,7 +17,7 @@ import numpy as np
 import pytest
 
 from repro.comm.engine import schedules_for
-from repro.compat import make_mesh
+from repro.launch.mesh import make_mesh
 from repro.configs.qwen3_moe_235b_a22b import tiny
 from repro.models import transformer as T
 from repro.models.kvcache import (PagedCacheConfig, PageAllocator,

@@ -17,7 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.compat import make_mesh
+from repro.launch.mesh import make_mesh
 from repro.configs import RunConfig
 from repro.configs.qwen3_moe_235b_a22b import tiny
 from repro.data import DataConfig, SyntheticLMDataset

@@ -1,6 +1,7 @@
 """Launches the distributed suite (tests/dist) in a fresh interpreter with 8
-placeholder CPU devices — the assignment forbids setting the device-count
-flag globally, so the main pytest process keeps 1 device."""
+placeholder CPU devices. The device-count flag must be set before JAX starts
+and would change every other test, so the main pytest process keeps one
+device and the multi-device tests get a process of their own."""
 from __future__ import annotations
 
 import os

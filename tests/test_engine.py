@@ -7,6 +7,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 import numpy as np
 import pytest
 
@@ -15,7 +16,7 @@ from repro.comm.engine import (OPS, CollectiveEngine, UnknownScheduleError,
                                schedules_for)
 from repro.comm.topology import AxisTopology, MeshTopology
 from repro.comm.types import CommunicationType as CT
-from repro.compat import make_mesh, shard_map
+from repro.launch.mesh import make_mesh
 from jax.sharding import PartitionSpec as P
 
 

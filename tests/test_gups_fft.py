@@ -10,7 +10,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.compat import make_mesh
+from repro.launch.mesh import make_mesh
 from repro.core import fft as FFT
 from repro.core import randomaccess as RA
 

@@ -1,5 +1,7 @@
 """Per-kernel validation: sweep shapes/dtypes, assert_allclose against the
-pure-jnp oracle (ref.py), interpret=True (the assignment's validation mode)."""
+pure-jnp oracle (ref.py). On the CPU the kernels run in Pallas interpret
+mode (kernels.ops.interpret_mode); tests/test_tpu_compile.py compiles them
+for a TPU."""
 from __future__ import annotations
 
 import jax

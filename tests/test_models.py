@@ -156,3 +156,27 @@ def test_remat_policies_same_loss(remat):
     _, m = step(state, _batch(cfg))
     # remat must not change numerics
     assert abs(float(m["loss"]) - 6.25) < 0.5
+
+
+def test_ssd_grad_finite_when_chunk_decay_overflows():
+    """Within a chunk, the cumulative decay sum(dt * A) can exceed float32's
+    exp range (mamba2-130m: 24 heads, chunk 256). The masked upper triangle
+    of the decay matrix must not turn the gradient into NaN."""
+    from repro.models.ssm import ssd_chunked, ssd_reference
+    k = jax.random.split(jax.random.key(0), 4)
+    b, L, H, P, G, N, Q = 1, 16, 2, 4, 1, 4, 8
+    x = jax.random.normal(k[0], (b, L, H, P))
+    dt = jnp.full((b, L, H), 1.0)
+    A = jnp.array([-1.0, -100.0])  # 8 steps x 100 = exp(800) above the diag
+    B = jax.random.normal(k[1], (b, L, G, N))
+    C = jax.random.normal(k[2], (b, L, G, N))
+
+    def loss(x, dt):
+        return jnp.sum(ssd_chunked(x, dt, A, B, C, Q)[0] ** 2)
+
+    grads = jax.grad(loss, argnums=(0, 1))(x, dt)
+    assert all(bool(jnp.all(jnp.isfinite(g))) for g in grads)
+    y, _ = ssd_chunked(x, dt, A, B, C, Q)
+    y_ref, _ = ssd_reference(x, dt, A, B, C)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref), rtol=1e-4,
+                               atol=1e-4)

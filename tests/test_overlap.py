@@ -8,10 +8,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
+from jax import shard_map
 
 from repro.comm.engine import CollectiveEngine, schedules_for
 from repro.comm.overlap import bucketed_psum_tree, pack_buckets, tree_bytes
-from repro.compat import make_mesh, shard_map
+from repro.launch.mesh import make_mesh
 
 
 def _leaves(*sizes):

@@ -6,17 +6,18 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
+from jax import shard_map
 
 hypothesis = pytest.importorskip(
     "hypothesis", reason="hypothesis not installed (optional [test] extra)")
-from hypothesis import given, settings, strategies as st  # noqa: E402
+from hypothesis import example, given, settings, strategies as st  # noqa: E402
 
 from repro.comm import compression
 from repro.comm.autotune import CostModel, route_links
 from repro.comm.engine import CollectiveEngine, schedules_for
 from repro.comm.topology import AxisTopology
 from repro.comm.types import TPU_V5E
-from repro.compat import make_mesh, shard_map
+from repro.launch.mesh import make_mesh
 from repro.core import models
 from repro.core.ptrans import distribute_cyclic, undistribute_cyclic
 from repro.data import DataConfig, SyntheticLMDataset
@@ -65,19 +66,23 @@ def test_distribution_preserves_multiset(pg, b, seed):
 @SETTINGS
 @given(size=st.integers(1, 2000), scale=st.floats(1e-3, 1e3),
        seed=st.integers(0, 2**31 - 1))
+@example(size=446, scale=48.0, seed=237)  # |x| ~ 150: past any fixed slack
 def test_quantize_error_bound(size, scale, seed):
-    """|x - deq(q(x))| <= max|block| / 127 / 2 per element (half-step)."""
+    """|x - deq(q(x))| <= max|block| / 127 / 2 per element (half-step),
+    plus float32 rounding of the scale, the quotient and the product — a
+    few ulps of the block maximum, so the slack scales with it."""
     rng = np.random.default_rng(seed)
     x = jnp.asarray(rng.standard_normal(size).astype(np.float32) * scale)
     q, s = compression.quantize(x)
     back = compression.dequantize(q, s, x.shape, x.size)
     blocks = np.asarray(jnp.pad(x, (0, (-x.size) % compression.BLOCK))
                         ).reshape(-1, compression.BLOCK)
-    bound = np.abs(blocks).max(axis=1) / 127.0 * 0.5 + 1e-7
+    amax = np.abs(blocks).max(axis=1)
+    bound = amax / 127.0 * 0.5 + 4 * np.finfo(np.float32).eps * amax
     err = np.abs(np.asarray(back) - np.asarray(x)).reshape(-1)
     per_block_err = np.pad(err, (0, (-err.size) % compression.BLOCK)
                            ).reshape(-1, compression.BLOCK)
-    assert (per_block_err.max(axis=1) <= bound + 1e-6).all()
+    assert (per_block_err.max(axis=1) <= bound).all()
 
 
 # --- fit_block always returns a divisor -------------------------------------
