@@ -80,6 +80,7 @@ All schedule bodies run inside ``shard_map``.
 """
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 from typing import Callable, Dict, Optional, Tuple
 
@@ -91,6 +92,7 @@ from jax.lax import axis_size
 from repro.comm.overlap import DEFAULT_BUCKET_BYTES, pack_buckets
 from repro.comm.topology import MeshTopology, ring_perm, transpose_perm
 from repro.comm.types import CommunicationType, comm_type
+from repro.scopes import ENGINE_OPS, engine_scope
 
 OPS: Tuple[str, ...] = ("bcast", "all_to_all_tiles", "allreduce",
                         "ring_exchange", "grid_transpose")
@@ -115,6 +117,20 @@ def _payload_bytes(x) -> Optional[int]:
         return int(x.size) * jnp.dtype(x.dtype).itemsize
     except (TypeError, AttributeError):
         return None
+
+
+def _scoped(method):
+    """Run an engine op's lowering under its scope,
+    ``comm.<op>:<callsite>`` (:func:`repro.scopes.engine_scope`), so a
+    profile attributes the op's device time to the op and its callsite."""
+    op = method.__name__
+    assert op in ENGINE_OPS, op
+
+    @functools.wraps(method)
+    def run(self, *args, callsite: Optional[str] = None, **kw):
+        with jax.named_scope(engine_scope(op, callsite)):
+            return method(self, *args, callsite=callsite, **kw)
+    return run
 
 
 class UnknownScheduleError(ValueError):
@@ -768,6 +784,7 @@ class CollectiveEngine:
 
     # -- ops (all run inside shard_map bodies) ------------------------------
 
+    @_scoped
     def bcast(self, val, axis, src, *, schedule: Optional[str] = None,
               callsite: Optional[str] = None):
         """Broadcast ``val`` from rank ``src`` (traced scalar ok) along
@@ -777,6 +794,7 @@ class CollectiveEngine:
                            axis=axis, callsite=callsite)
         return fn(self, val, axis, src)
 
+    @_scoped
     def all_to_all_tiles(self, x, axis, *, split_axis: int, concat_axis: int,
                          schedule: Optional[str] = None,
                          callsite: Optional[str] = None):
@@ -802,6 +820,7 @@ class CollectiveEngine:
         return fn(self, x, axis, split_axis=split_axis,
                   concat_axis=concat_axis)
 
+    @_scoped
     def allreduce(self, x, axis, *, schedule: Optional[str] = None,
                   callsite: Optional[str] = None):
         """Sum ``x`` over all ranks of ``axis`` (a name or tuple of names)."""
@@ -828,6 +847,7 @@ class CollectiveEngine:
         hw = getattr(model, "hw", None) or default_cost_model().hw
         return derive_bucket_bytes(axes, hw)
 
+    @_scoped
     def allreduce_tree(self, tree, axis, *,
                        bucket_bytes: Optional[int] = None,
                        schedule: Optional[str] = None,
@@ -869,6 +889,7 @@ class CollectiveEngine:
                     off += n
         return jax.tree.unflatten(treedef, out)
 
+    @_scoped
     def ring_exchange(self, x_fwd, x_bwd, axis, *,
                       schedule: Optional[str] = None,
                       callsite: Optional[str] = None):
@@ -880,6 +901,7 @@ class CollectiveEngine:
                            callsite=callsite)
         return fn(self, x_fwd, x_bwd, axis)
 
+    @_scoped
     def grid_transpose(self, x, axes, pg: int, *,
                        schedule: Optional[str] = None,
                        callsite: Optional[str] = None):
@@ -893,6 +915,7 @@ class CollectiveEngine:
 
     # -- pipelined transform ------------------------------------------------
 
+    @_scoped
     def pipelined(self, op: str, x, axis, *, nchunks="auto",
                   split_axis: int = 0, concat_axis: Optional[int] = None,
                   consume: Optional[Callable] = None,

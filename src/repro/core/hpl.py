@@ -61,6 +61,8 @@ from repro.core.models import hpl_flops
 from repro.core.ptrans import distribute_cyclic, undistribute_cyclic
 from repro.kernels.ops import (gemm_update, lu_factor_block,
                                trsm_lower_left, trsm_upper_right)
+from repro.scopes import (HPL_FACTOR, HPL_STRIP, HPL_TRSM, HPL_UPDATE,
+                          HPL_WRITEBACK)
 
 
 # ---------------------------------------------------------------------------
@@ -107,20 +109,23 @@ def _panels(k, diag, row_panel, col_panel, *, pg: int, b: int,
     pk = k % pg
 
     # 1. diagonal block (speculative on every device; selected by bcast)
-    lu_local = lu_factor_block(diag)
+    with jax.named_scope(HPL_FACTOR):
+        lu_local = lu_factor_block(diag)
     lu_blk = engine.bcast(lu_local, "cols", pk, callsite=HPL_BLOCK)
     lu_blk = engine.bcast(lu_blk, "rows", pk, callsite=HPL_BLOCK)
 
     # 2. Top panel: U_kj = L_kk^{-1} A_kj on grid row pk, cols j > k
-    u_panel = trsm_lower_left(lu_blk, row_panel)
-    colmask = jnp.repeat(lj_global > k, b)  # (m,)
-    u_panel = u_panel * colmask[None, :]
+    with jax.named_scope(HPL_TRSM):
+        u_panel = trsm_lower_left(lu_blk, row_panel)
+        colmask = jnp.repeat(lj_global > k, b)  # (m,)
+        u_panel = u_panel * colmask[None, :]
     u_panel = engine.bcast(u_panel, "rows", pk, callsite=HPL_PANEL)
 
     # 3. Left panel: L_ik = A_ik U_kk^{-1} on grid col pk, rows i > k
-    l_panel = trsm_upper_right(lu_blk, col_panel)
-    rowmask = jnp.repeat(li_global > k, b)
-    l_panel = l_panel * rowmask[:, None]
+    with jax.named_scope(HPL_TRSM):
+        l_panel = trsm_upper_right(lu_blk, col_panel)
+        rowmask = jnp.repeat(li_global > k, b)
+        l_panel = l_panel * rowmask[:, None]
     l_panel = engine.bcast(l_panel, "cols", pk, callsite=HPL_PANEL)
     return lu_blk, u_panel, l_panel
 
@@ -132,26 +137,29 @@ def _update_writeback(k, a, lu_blk, u_panel, l_panel, *, pg: int, b: int,
     m = lb * b
     pk = k % pg
     lk = k // pg
-    colmask = jnp.repeat(lj_global > k, b)
-    rowmask = jnp.repeat(li_global > k, b)
+    with jax.named_scope(HPL_WRITEBACK):
+        colmask = jnp.repeat(lj_global > k, b)
+        rowmask = jnp.repeat(li_global > k, b)
 
     # 4. trailing update: masks zero the factored rows/cols
-    a = gemm_update(a, l_panel, u_panel, alpha=-1.0, bm=b, bn=b)
+    with jax.named_scope(HPL_UPDATE):
+        a = gemm_update(a, l_panel, u_panel, alpha=-1.0, bm=b, bn=b)
 
     # 5. write back factored panels. The rank masks are folded INTO the
     # update values so every write is one slice-sized dynamic-update-slice —
     # a `where(r == pk, dus(a, ...), a)` select would touch the full local
     # matrix three times per iteration (measured as the second-largest HBM
     # term of the production HPL lowering, §Perf iteration C1).
-    old_row = lax.dynamic_slice(a, (lk * b, 0), (b, m))
-    new_row = jnp.where(colmask[None, :] & (r == pk), u_panel, old_row)
-    a = lax.dynamic_update_slice(a, new_row, (lk * b, 0))
-    old_col = lax.dynamic_slice(a, (0, lk * b), (m, b))
-    new_col = jnp.where(rowmask[:, None] & (c == pk), l_panel, old_col)
-    a = lax.dynamic_update_slice(a, new_col, (0, lk * b))
-    old_diag = lax.dynamic_slice(a, (lk * b, lk * b), (b, b))
-    new_diag = jnp.where((r == pk) & (c == pk), lu_blk, old_diag)
-    a = lax.dynamic_update_slice(a, new_diag, (lk * b, lk * b))
+    with jax.named_scope(HPL_WRITEBACK):
+        old_row = lax.dynamic_slice(a, (lk * b, 0), (b, m))
+        new_row = jnp.where(colmask[None, :] & (r == pk), u_panel, old_row)
+        a = lax.dynamic_update_slice(a, new_row, (lk * b, 0))
+        old_col = lax.dynamic_slice(a, (0, lk * b), (m, b))
+        new_col = jnp.where(rowmask[:, None] & (c == pk), l_panel, old_col)
+        a = lax.dynamic_update_slice(a, new_col, (0, lk * b))
+        old_diag = lax.dynamic_slice(a, (lk * b, lk * b), (b, b))
+        new_diag = jnp.where((r == pk) & (c == pk), lu_blk, old_diag)
+        a = lax.dynamic_update_slice(a, new_diag, (lk * b, lk * b))
     return a
 
 
@@ -160,9 +168,11 @@ def _iteration(k, a, *, pg: int, b: int, lb: int, engine: CollectiveEngine,
     """Eager iteration: factor+broadcast panels for k, then update."""
     m = lb * b
     lk = k // pg
-    diag = lax.dynamic_slice(a, (lk * b, lk * b), (b, b))
-    row_panel = lax.dynamic_slice(a, (lk * b, 0), (b, m))
-    col_panel = lax.dynamic_slice(a, (0, lk * b), (m, b))
+    with jax.named_scope(HPL_FACTOR):
+        diag = lax.dynamic_slice(a, (lk * b, lk * b), (b, b))
+    with jax.named_scope(HPL_TRSM):
+        row_panel = lax.dynamic_slice(a, (lk * b, 0), (b, m))
+        col_panel = lax.dynamic_slice(a, (0, lk * b), (m, b))
     lu_blk, u_panel, l_panel = _panels(
         k, diag, row_panel, col_panel, pg=pg, b=b, engine=engine,
         li_global=li_global, lj_global=lj_global)
@@ -182,16 +192,18 @@ def _strip_panels(kidx, a, flight, *, pg: int, b: int, lb: int,
     an already-updated band. ``kidx`` may be traced."""
     m = lb * b
     lk = kidx // pg
-    row_strip = lax.dynamic_slice(a, (lk * b, 0), (b, m))
-    col_strip = lax.dynamic_slice(a, (0, lk * b), (m, b))
-    for lu_blk, u_panel, l_panel in flight:
-        l_rows = lax.dynamic_slice(l_panel, (lk * b, 0), (b, b))
-        row_strip = gemm_update(row_strip, l_rows, u_panel, alpha=-1.0,
-                                bm=b, bn=b)
-        u_cols = lax.dynamic_slice(u_panel, (0, lk * b), (b, b))
-        col_strip = gemm_update(col_strip, l_panel, u_cols, alpha=-1.0,
-                                bm=b, bn=b)
-    diag = lax.dynamic_slice(col_strip, (lk * b, 0), (b, b))
+    with jax.named_scope(HPL_STRIP):
+        row_strip = lax.dynamic_slice(a, (lk * b, 0), (b, m))
+        col_strip = lax.dynamic_slice(a, (0, lk * b), (m, b))
+        for lu_blk, u_panel, l_panel in flight:
+            l_rows = lax.dynamic_slice(l_panel, (lk * b, 0), (b, b))
+            row_strip = gemm_update(row_strip, l_rows, u_panel, alpha=-1.0,
+                                    bm=b, bn=b)
+            u_cols = lax.dynamic_slice(u_panel, (0, lk * b), (b, b))
+            col_strip = gemm_update(col_strip, l_panel, u_cols, alpha=-1.0,
+                                    bm=b, bn=b)
+    with jax.named_scope(HPL_FACTOR):
+        diag = lax.dynamic_slice(col_strip, (lk * b, 0), (b, b))
     return _panels(kidx, diag, row_strip, col_strip, pg=pg, b=b,
                    engine=engine, li_global=li_global, lj_global=lj_global)
 

@@ -48,6 +48,7 @@ from repro.comm.engine import CollectiveEngine
 from repro.comm.types import CommunicationType
 from repro.core.hpcc import BenchResult, register, timeit
 from repro.kernels.ops import transpose_add
+from repro.scopes import PTRANS_TRANSPOSE_ADD
 
 
 # ---------------------------------------------------------------------------
@@ -101,15 +102,17 @@ def _ptrans_body(a_loc, b_loc, *, pg: int, engine: CollectiveEngine,
     if nchunks <= 1:
         recv = engine.grid_transpose(a_loc, ("rows", "cols"), pg,
                                      callsite=CALLSITE)
-        out = transpose_add(recv, b_loc)
+        with jax.named_scope(PTRANS_TRANSPOSE_ADD):
+            out = transpose_add(recv, b_loc)
         return out[None]
 
     # strip-wise pipeline: row strip i of A lands, its transpose-add writes
     # column strip i of C while strip i+1 is still on the wire
     def consume(strip, start):
-        b_cols = lax.slice_in_dim(b_loc, start, start + strip.shape[0],
-                                  axis=1)
-        return transpose_add(strip, b_cols)
+        with jax.named_scope(PTRANS_TRANSPOSE_ADD):
+            b_cols = lax.slice_in_dim(b_loc, start, start + strip.shape[0],
+                                      axis=1)
+            return transpose_add(strip, b_cols)
 
     out = engine.pipelined("grid_transpose", a_loc, ("rows", "cols"),
                            pg=pg, nchunks=nchunks, split_axis=0,

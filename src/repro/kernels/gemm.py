@@ -87,6 +87,7 @@ def matmul(a: jnp.ndarray, b: jnp.ndarray, *, bm: int = 256, bn: int = 256,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+        name="matmul",
         interpret=interpret,
     )(a, b)
 
@@ -132,5 +133,6 @@ def gemm_update(c: jnp.ndarray, a: jnp.ndarray, b: jnp.ndarray, *,
         out_shape=jax.ShapeDtypeStruct((M, N), c.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         input_output_aliases=alias,
+        name="gemm_update",
         interpret=interpret,
     )(c, a, b)

@@ -77,6 +77,7 @@ def lu_factor_block(a: jnp.ndarray, *, interpret: bool = False) -> jnp.ndarray:
         out_specs=pl.BlockSpec((n, n), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((n, n), a.dtype),
         scratch_shapes=[pltpu.VMEM((n, n), jnp.float32)],
+        name="lu_factor_block",
         interpret=interpret,
     )(a)
 
@@ -124,6 +125,7 @@ def trsm_lower_left(lu: jnp.ndarray, b: jnp.ndarray, *, bn: int = 256,
         out_specs=pl.BlockSpec((n, bn), lambda j: (0, j)),
         out_shape=jax.ShapeDtypeStruct(b.shape, b.dtype),
         scratch_shapes=[pltpu.VMEM((n, bn), jnp.float32)],
+        name="trsm_lower_left",
         interpret=interpret,
     )(lu, b)
 
@@ -167,5 +169,6 @@ def trsm_upper_right(lu: jnp.ndarray, b: jnp.ndarray, *, bm: int = 256,
         out_specs=pl.BlockSpec((bm, n), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(b.shape, b.dtype),
         scratch_shapes=[pltpu.VMEM((bm, n), jnp.float32)],
+        name="trsm_upper_right",
         interpret=interpret,
     )(lu, b)

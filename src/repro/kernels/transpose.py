@@ -47,5 +47,6 @@ def transpose_add(a: jnp.ndarray, b: jnp.ndarray, *, block: int = 256,
         ],
         out_specs=pl.BlockSpec((bs, bs), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((N, M), b.dtype),
+        name="transpose_add",
         interpret=interpret,
     )(a, b)

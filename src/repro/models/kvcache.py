@@ -171,8 +171,12 @@ class PageAllocator:
         self._free_slots.append(slot)
 
     def device_tables(self):
-        """(block_table, seq_lens) as device arrays for the decode step."""
-        return jnp.asarray(self.block_table), jnp.asarray(self.seq_lens)
+        """(block_table, seq_lens) as device arrays for the decode step.
+        Copies: the allocator changes its host arrays in place, and a device
+        array made from a numpy array without a copy may share its memory
+        (zero-copy on the CPU backend), so an earlier step's tables would
+        change under it."""
+        return jnp.array(self.block_table), jnp.array(self.seq_lens)
 
 
 def gather_pages(pages: jnp.ndarray, block_table: jnp.ndarray) -> jnp.ndarray:

@@ -105,6 +105,72 @@ def test_tuned_patterns_exist_in_autotune():
 
 
 # ---------------------------------------------------------------------------
+# README scope table <-> repro.scopes
+# ---------------------------------------------------------------------------
+
+
+def _scope_table_rows():
+    """Parse the '### Scope registry' table into {scope: owning module}."""
+    with open(README, encoding="utf-8") as f:
+        text = f.read()
+    m = re.search(r"#+\s*Scope registry(.*?)(?:\n#|\Z)", text, re.DOTALL)
+    assert m, "README is missing the 'Scope registry' section"
+    rows = {}
+    for line in m.group(1).splitlines():
+        if not line.strip().startswith("|"):
+            continue
+        cells = [c.strip().strip("`") for c in line.strip().strip("|")
+                 .split("|")]
+        if len(cells) < 3 or cells[0] in ("scope", "") \
+                or set(cells[0]) <= {"-"}:
+            continue
+        rows[cells[0]] = cells[-1]
+    return rows
+
+
+def test_readme_scope_table_matches_registry():
+    from repro import scopes as SC
+    rows = _scope_table_rows()
+    engine_row = SC.engine_scope("<op>", "<callsite>")
+    assert set(rows) == set(SC.PHASES) | {engine_row}, (
+        f"README scope rows {sorted(rows)} != registry phases "
+        f"{sorted(SC.PHASES)} + {engine_row!r}")
+    for name, module in rows.items():
+        want = (SC.ENGINE_MODULE if name == engine_row
+                else SC.PHASES[name].module)
+        assert module == want, (name, module, want)
+
+
+def test_scope_constants_wrap_code_in_owning_modules():
+    """Each phase constant is imported from repro.scopes by its owning
+    module and opens a named scope there; the engine applies its rule in
+    one helper that every public op goes through."""
+    from repro import scopes as SC
+    for name, scope in SC.PHASES.items():
+        assert getattr(SC, scope.const) == name, (scope.const, name)
+        src = inspect.getsource(importlib.import_module(scope.module))
+        assert re.search(r"from repro\.scopes import", src), scope.module
+        assert re.search(rf"jax\.named_scope\({scope.const}\)", src), (
+            f"{scope.module} opens no named scope {scope.const}")
+        assert f'"{name}"' not in src, (
+            f"{scope.module} inlines the literal {name!r}")
+    from repro.comm.engine import CollectiveEngine
+    for op in SC.ENGINE_OPS:
+        assert getattr(CollectiveEngine, op).__wrapped__.__name__ == op
+
+
+def test_every_callsite_tag_has_engine_scopes():
+    from repro import scopes as SC
+    names = SC.registered()
+    for tag in (*CS.CALLSITES, None):
+        for op in SC.ENGINE_OPS:
+            assert names[SC.engine_scope(op, tag)] == SC.ENGINE_MODULE
+    assert SC.engine_scope("bcast", CS.HPL_PANEL) == "comm.bcast:hpl.panel"
+    assert SC.engine_scope("allreduce") == "comm.allreduce:untagged"
+    assert all("@" not in n and "/" not in n for n in names)
+
+
+# ---------------------------------------------------------------------------
 # resilience docs <-> code
 # ---------------------------------------------------------------------------
 

@@ -221,3 +221,27 @@ def test_fused_ring_step_matches_plain_add():
     a2, b2 = a.reshape(-1)[:100], b.reshape(-1)[:100]
     np.testing.assert_array_equal(np.asarray(fused_chunk_add(a2, b2)),
                                   np.asarray(a2 + b2))
+
+
+def test_engine_ops_run_under_their_callsite_scope():
+    """Every engine op lowers under ``comm.<op>:<callsite>`` (``untagged``
+    without a tag), one scope per op, nested calls under their own."""
+    import re
+    from repro.comm.callsites import DP_GRADS
+    mesh = make_mesh((1,), ("x",))
+    # staged emits ops on a single rank, where the others are identities
+    eng = CollectiveEngine.for_mesh(mesh, CT.HOST_STAGED)
+
+    def body(v):
+        a = eng.allreduce(v, "x", callsite=DP_GRADS)
+        b = eng.bcast(a, "x", 0)
+        return eng.pipelined("allreduce", b, "x", nchunks=2,
+                             callsite=DP_GRADS, consume=lambda s, i: s * 2)
+
+    f = jax.jit(shard_map(body, mesh=mesh, in_specs=P("x"),
+                          out_specs=P("x"), check_vma=False))
+    text = f.lower(jnp.ones((8, 128))).as_text(debug_info=True)
+    found = set(re.findall(r"comm\.[a-z_]+:[a-z_.]+(?:/comm\.[a-z_]+:"
+                           r"[a-z_.]+)?", text))
+    assert {"comm.allreduce:dp.grads", "comm.bcast:untagged",
+            "comm.pipelined:dp.grads/comm.allreduce:dp.grads"} <= found

@@ -88,6 +88,20 @@ def test_allocate_append_release_roundtrip():
     assert alloc.seq_lens[s] == 0
 
 
+def test_device_tables_do_not_follow_later_host_changes():
+    """A step's tables stay what they were when taken, whatever the
+    allocator does to its host arrays afterwards."""
+    alloc = PageAllocator(_pcfg())
+    s = alloc.allocate(10)
+    alloc.commit(s, 6)
+    bt, ln = alloc.device_tables()
+    before = (np.array(bt), np.array(ln))
+    alloc.append(s, 2)
+    alloc.release(s)
+    assert (np.asarray(bt) == before[0]).all()
+    assert (np.asarray(ln) == before[1]).all() and int(ln[s]) == 6
+
+
 def test_allocator_exhaustion_and_recycle():
     alloc = PageAllocator(_pcfg())  # 8 pages
     a = alloc.allocate(16)  # 4 pages

@@ -107,3 +107,51 @@ def test_hpl_factorization_compiles_for_v5e(topo, monkeypatch):
         sharding=NamedSharding(mesh, P(("rows", "cols"), None, None)))
     fact = make_factorize(mesh, pg=1, nb=n // b, b=b, lookahead=1)
     assert "tpu_custom_call" in fact.lower(spec).compile().as_text()
+
+
+def _ops_and_phases(text):
+    """HLO instruction names and the registered scopes their op_names
+    carry, in the compiled module text."""
+    import re
+    from repro.scopes import registered
+    names = registered()
+    ops, phases = set(), set()
+    for m in re.finditer(r'^\s*(?:ROOT\s+)?%([\w.\-]+) = .*?metadata=\{'
+                         r'op_name="([^"]*)"', text, re.M):
+        ops.add(m.group(1).split(".")[0])
+        phases |= {p for p in m.group(2).split("/") if p in names}
+    return ops, phases
+
+
+@pytest.mark.parametrize("pg", [1, 2])
+def test_kernel_names_and_scopes_in_the_compiled_programs(topo, monkeypatch,
+                                                          pg):
+    """The Pallas kernels keep the instruction names the trace shows
+    (``gemm_update``, ``lu_factor_block``, ``trsm_*``, ``transpose_add``),
+    and every HPL and PTRANS phase and the engine's callsite scopes reach
+    the compiled programs' op_name metadata, on one chip and on 2x2."""
+    from repro.comm.engine import CollectiveEngine
+    from repro.core.hpl import make_factorize
+    from repro.core.ptrans import make_step
+    from repro.kernels import ops
+    monkeypatch.setattr(ops, "interpret_mode", lambda: False)
+    mesh = Mesh(np.array(topo.devices[:pg * pg]).reshape(pg, pg),
+                ("rows", "cols"))
+    n, b = 1024 * pg, 256
+    spec = jax.ShapeDtypeStruct(
+        (pg * pg, n // pg, n // pg), F32,
+        sharding=NamedSharding(mesh, P(("rows", "cols"), None, None)))
+    hpl = make_factorize(mesh, pg=pg, nb=n // b, b=b, lookahead=1)
+    names, phases = _ops_and_phases(hpl.lower(spec).compile().as_text())
+    assert {"gemm_update", "lu_factor_block", "trsm_lower_left",
+            "trsm_upper_right"} <= names
+    assert {"hpl.factor", "hpl.trsm", "hpl.update", "hpl.strip",
+            "hpl.writeback"} <= phases
+    if pg > 1:
+        assert {"comm.bcast:hpl.block", "comm.bcast:hpl.panel"} <= phases
+    step = make_step(mesh, pg, CollectiveEngine.for_mesh(mesh))
+    names, phases = _ops_and_phases(step.lower(spec, spec).compile()
+                                    .as_text())
+    assert "transpose_add" in names and "collective-permute-start" in names
+    assert {"ptrans.transpose_add",
+            "comm.grid_transpose:ptrans.exchange"} <= phases
