@@ -16,10 +16,18 @@ Per iteration k (paper Fig. 4):
   5. every device applies the trailing rank-b GEMM update on its local
      blocks                                                 [gemm_update]
 
-The masks that restrict panels to i,j > k are *multiplicative* (zeroed rows/
-columns), so the trailing update needs no selects — a zeroed panel row
-contributes nothing, exactly like the paper's "blocks left/above need no
-further processing".
+Trailing-tile grids: in iteration k each device launches its GEMM and its
+two panel solves only over the local b x b tiles whose global row or column
+is past k (:func:`first_unfactored` gives where they start; the kernels
+take it as a traced first tile, a dynamic grid bound, and leave the tiles
+before it as they were), like the paper's "blocks left/above need no
+further processing". The panels are still masked *multiplicatively*
+(their factored rows/columns zeroed), and the factorization is bit for bit
+the one a full-grid launch gives: a skipped GEMM tile would compute
+C - (0 @ U), which is C, and a skipped TRSM tile would be zeroed by the
+mask; the tile shape stays b x b, so every launched tile does the same
+arithmetic. :func:`launched_tiles` counts the launched work, which equals
+the required update sum_k 2 b (rows past k)(columns past k).
 
 Lookahead (paper Fig. 5/7 overlap) — ``lookahead=d`` (``True`` == 1) keeps
 ``d`` panel pipelines in flight: per iteration k, only the row/column strips
@@ -31,14 +39,15 @@ and only then is the bulk trailing GEMM of iteration k applied. The k+d
 broadcasts depend solely on the strips, so XLA can interleave the
 ``chain``/``ring2d`` hops of up to d iterations with the bulk updates —
 covering the broadcast latency of small blocks on large tori. The bulk GEMM
-still covers the full local matrix (the strip work is redundant compute,
-~2db/m of the update FLOPs), which keeps the factorization bit-identical to
-eager mode for every d: every matrix element takes its value from the same
-full-GEMM arithmetic; the strip GEMM sequence applied to the k+d band is
-per-element identical to the same d full GEMMs restricted to the band; and
-the k+d panels never read global row/column <= k+d-1 (masked), the only
-entries whose values the pending write-backs would change. The depth can be
-resolved from the cost model (``lookahead="auto"`` in :func:`run_hpl` →
+is eager mode's, over the same trailing tiles (the strip GEMMs keep the full
+strip: redundant compute, ~2db/m of the update FLOPs), which keeps the
+factorization bit-identical to eager mode for every d: every matrix element
+takes its value from the same trailing-tile GEMM arithmetic; the strip GEMM
+sequence applied to the k+d band is per-element identical to the same d
+GEMMs restricted to the band; and the k+d panels never read global
+row/column <= k+d-1 (masked), the only entries whose values the pending
+write-backs would change. The depth can be resolved from the cost model
+(``lookahead="auto"`` in :func:`run_hpl` →
 :func:`repro.comm.autotune.choose_hpl_depth`).
 """
 from __future__ import annotations
@@ -99,6 +108,33 @@ def normalized_residual(a: np.ndarray, x: np.ndarray, b: np.ndarray) -> float:
 # ---------------------------------------------------------------------------
 
 
+def first_unfactored(k, li_global, lj_global):
+    """This device's first local row block and first local column block
+    whose global index is past ``k``: iteration k's trailing tiles. Global
+    indices rise along the local blocks, so every block from these on is
+    unfactored. On one chip both are ``k + 1``; on a P x P torus they differ
+    per device. Takes numpy or traced index arrays alike."""
+    return (li_global <= k).sum(), (lj_global <= k).sum()
+
+
+def launched_tiles(nb: int, pg: int, r: int, c: int) -> Tuple[int, int]:
+    """(update tiles, TRSM tiles) of b x b that one factorization launches
+    on device (r, c): iteration k's GEMM over its trailing row x column
+    tiles, and its two panel solves over its trailing column and row
+    tiles (every device solves, the broadcasts select). The lookahead
+    strips are not counted, nor panels of the clamped last iteration, which
+    have no trailing tiles."""
+    lb = nb // pg
+    li_global = np.arange(lb) * pg + r
+    lj_global = np.arange(lb) * pg + c
+    update = trsm = 0
+    for k in range(nb):
+        r0, c0 = first_unfactored(k, li_global, lj_global)
+        update += int((lb - r0) * (lb - c0))
+        trsm += int((lb - r0) + (lb - c0))
+    return update, trsm
+
+
 def _panels(k, diag, row_panel, col_panel, *, pg: int, b: int,
             engine: CollectiveEngine, li_global, lj_global):
     """Factor the diagonal block and form + broadcast iteration ``k``'s U/L
@@ -107,6 +143,7 @@ def _panels(k, diag, row_panel, col_panel, *, pg: int, b: int,
     the first k rank-b updates. Returns (lu_blk, u_panel, l_panel), all
     broadcast grid-wide."""
     pk = k % pg
+    r0, c0 = first_unfactored(k, li_global, lj_global)
 
     # 1. diagonal block (speculative on every device; selected by bcast)
     with jax.named_scope(HPL_FACTOR):
@@ -114,16 +151,17 @@ def _panels(k, diag, row_panel, col_panel, *, pg: int, b: int,
     lu_blk = engine.bcast(lu_local, "cols", pk, callsite=HPL_BLOCK)
     lu_blk = engine.bcast(lu_blk, "rows", pk, callsite=HPL_BLOCK)
 
-    # 2. Top panel: U_kj = L_kk^{-1} A_kj on grid row pk, cols j > k
+    # 2. Top panel: U_kj = L_kk^{-1} A_kj on grid row pk, cols j > k (the
+    # solve runs over those tiles only; the mask zeroes the others)
     with jax.named_scope(HPL_TRSM):
-        u_panel = trsm_lower_left(lu_blk, row_panel)
+        u_panel = trsm_lower_left(lu_blk, row_panel, bn=b, first=c0)
         colmask = jnp.repeat(lj_global > k, b)  # (m,)
         u_panel = u_panel * colmask[None, :]
     u_panel = engine.bcast(u_panel, "rows", pk, callsite=HPL_PANEL)
 
     # 3. Left panel: L_ik = A_ik U_kk^{-1} on grid col pk, rows i > k
     with jax.named_scope(HPL_TRSM):
-        l_panel = trsm_upper_right(lu_blk, col_panel)
+        l_panel = trsm_upper_right(lu_blk, col_panel, bm=b, first=r0)
         rowmask = jnp.repeat(li_global > k, b)
         l_panel = l_panel * rowmask[:, None]
     l_panel = engine.bcast(l_panel, "cols", pk, callsite=HPL_PANEL)
@@ -132,8 +170,8 @@ def _panels(k, diag, row_panel, col_panel, *, pg: int, b: int,
 
 def _update_writeback(k, a, lu_blk, u_panel, l_panel, *, pg: int, b: int,
                       lb: int, r, c, li_global, lj_global):
-    """Apply iteration ``k``'s trailing rank-b GEMM over the full local
-    matrix and write back the factored panels."""
+    """Apply iteration ``k``'s trailing rank-b GEMM over this device's
+    trailing tiles and write back the factored panels."""
     m = lb * b
     pk = k % pg
     lk = k // pg
@@ -141,9 +179,11 @@ def _update_writeback(k, a, lu_blk, u_panel, l_panel, *, pg: int, b: int,
         colmask = jnp.repeat(lj_global > k, b)
         rowmask = jnp.repeat(li_global > k, b)
 
-    # 4. trailing update: masks zero the factored rows/cols
+    # 4. trailing update, launched over the unfactored rows x cols only; the
+    # factored tiles keep their values, as C - (0 @ U) would leave them
     with jax.named_scope(HPL_UPDATE):
-        a = gemm_update(a, l_panel, u_panel, alpha=-1.0, bm=b, bn=b)
+        a = gemm_update(a, l_panel, u_panel, alpha=-1.0, bm=b, bn=b,
+                        first=first_unfactored(k, li_global, lj_global))
 
     # 5. write back factored panels. The rank masks are folded INTO the
     # update values so every write is one slice-sized dynamic-update-slice —
@@ -219,11 +259,11 @@ def _iteration_lookahead(k, carry, *, pg: int, nb: int, b: int, lb: int,
     hops depend only on the thin strip GEMMs, so XLA is free to overlap up
     to d iterations' broadcasts with the bulk updates.
 
-    Bit-identity with eager mode, for every d: the bulk GEMM below still
-    covers the full local matrix, so every element of ``a`` takes its value
-    from exactly the eager arithmetic; the strip GEMM sequence is
-    per-element identical to the same full GEMMs restricted to the strip
-    (single k-block of b <= bk columns — asserted by
+    Bit-identity with eager mode, for every d: the bulk GEMM below is
+    eager mode's, over the same trailing tiles, so every element of ``a``
+    takes its value from exactly the eager arithmetic; the strip GEMM
+    sequence is per-element identical to the same GEMMs restricted to the
+    strip (single k-block of b <= bk columns — asserted by
     tests/dist/test_overlap.py); and the k+d panels never read global
     row/column <= k+d-1 (masked multiplicatively), the only entries the
     pending write-backs would change."""

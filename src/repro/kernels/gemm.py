@@ -10,6 +10,11 @@ accumulator (256x256x4 B) = 512 KiB working set, comfortably inside the
 The paper's two-level blocking (LOCAL_MEM_BLOCK / REGISTER_BLOCK, Table 4)
 maps to: level 1 = the BlockSpec HBM->VMEM tile; level 2 = the MXU's native
 128x128 systolic tile, which jnp.dot inside the kernel lowers onto.
+
+``gemm_update`` launches over the trailing tiles from a first row and
+column tile (scalar prefetch; traced, a dynamic grid bound): HPL's
+iteration k updates only the rows and columns past block k, a third of a
+full-grid update's 2 N^3 over a factorization.
 """
 from __future__ import annotations
 
@@ -92,8 +97,10 @@ def matmul(a: jnp.ndarray, b: jnp.ndarray, *, bm: int = 256, bn: int = 256,
     )(a, b)
 
 
-def _gemm_update_kernel(c_ref, a_ref, b_ref, o_ref, acc_ref, *, nk: int,
-                        alpha: float):
+def _gemm_update_kernel(first_ref, c_ref, a_ref, b_ref, o_ref, acc_ref, *,
+                        nk: int, alpha: float):
+    del first_ref  # read by the index maps only
+
     @pl.when(pl.program_id(2) == 0)
     def _init():
         acc_ref[...] = c_ref[...].astype(jnp.float32)
@@ -107,32 +114,54 @@ def _gemm_update_kernel(c_ref, a_ref, b_ref, o_ref, acc_ref, *, nk: int,
 
 def gemm_update(c: jnp.ndarray, a: jnp.ndarray, b: jnp.ndarray, *,
                 alpha: float = -1.0, bm: int = 256, bn: int = 256,
-                bk: int = 256, interpret: bool = False) -> jnp.ndarray:
-    """C <- C + alpha * A @ B (HPL trailing update with alpha = -1).
+                bk: int = 256, first=(0, 0),
+                interpret: bool = False) -> jnp.ndarray:
+    """C <- C + alpha * A @ B (HPL trailing update with alpha = -1) on the
+    trailing tiles: row tiles from ``first[0]`` and column tiles from
+    ``first[1]`` (each an int or a traced int32 scalar, at most the tile
+    count; the tile count itself gives an empty grid). Tiles before them
+    are not launched and keep C's values.
 
     The output buffer aliases C (in-place on TPU) — the HPL trailing matrix
-    is updated without a second HBM allocation.
+    is updated without a second HBM allocation, and the tiles outside the
+    grid are C's own. The first tiles reach the index maps as scalar
+    prefetch; a traced first tile gives a dynamic grid bound.
     """
     M, K = a.shape
     _, N = b.shape
     assert c.shape == (M, N)
     bm, bn, bk = _blocks(M, N, K, bm, bn, bk, interpret)
-    grid = (M // bm, N // bn, K // bk)
-    # aliasing is the TPU in-place path; interpret mode implements donation
-    # with a defensive whole-buffer copy per grid step (measured, §Perf C3)
-    alias = {} if interpret else {0: 0}
+    mt, nt = M // bm, N // bn
+    r0, c0 = first
+    grid = (mt - r0, nt - c0, K // bk)
+    starts = jnp.stack([jnp.asarray(r0, jnp.int32),
+                        jnp.asarray(c0, jnp.int32)])
+
+    # an empty grid leaves nothing to fetch; the clamp keeps the block
+    # index of any prefetch the pipeline may issue inside the array
+    def row(i, s):
+        return jnp.minimum(i + s[0], mt - 1)
+
+    def col(j, s):
+        return jnp.minimum(j + s[1], nt - 1)
+
     return pl.pallas_call(
         partial(_gemm_update_kernel, nk=grid[2], alpha=alpha),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
-            pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
-            pl.BlockSpec((bk, bn), lambda i, j, k: (k, j)),
-        ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec((bm, bn), lambda i, j, k, s: (row(i, s),
+                                                           col(j, s))),
+                pl.BlockSpec((bm, bk), lambda i, j, k, s: (row(i, s), k)),
+                pl.BlockSpec((bk, bn), lambda i, j, k, s: (k, col(j, s))),
+            ],
+            out_specs=pl.BlockSpec((bm, bn), lambda i, j, k, s: (row(i, s),
+                                                                 col(j, s))),
+            scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+        ),
         out_shape=jax.ShapeDtypeStruct((M, N), c.dtype),
-        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        input_output_aliases=alias,
+        input_output_aliases={1: 0},
         name="gemm_update",
         interpret=interpret,
-    )(c, a, b)
+    )(starts, c, a, b)

@@ -10,7 +10,10 @@ The diagonal factorization and the triangular solves are sequential over the
 block dimension — that is inherent to LU — but they touch O(b^2) data while
 the trailing GEMMs touch O(n^2) per iteration, so these kernels sit off the
 critical roofline for large n (paper Fig. 13: performance converges to the
-matmul bound).
+matmul bound). The panel solves take a first tile (a traced int32 scalar
+is a dynamic grid bound) and solve only the tiles from it on: HPL's
+iteration k needs the panel past block k alone. Their output aliases the
+panel, so the tiles before it keep the input's finite values.
 
 Every step is written as full-tile masked VPU arithmetic on a float32 VMEM
 scratch: row ``k`` and column ``k`` are extracted with 2-D iota masks and a
@@ -87,11 +90,12 @@ def lu_factor_block(a: jnp.ndarray, *, interpret: bool = False) -> jnp.ndarray:
 # ---------------------------------------------------------------------------
 
 
-def _trsm_lower_kernel(lu_ref, b_ref, o_ref, x_ref):
+def _trsm_lower_kernel(first_ref, lu_ref, b_ref, o_ref, x_ref):
     """Solve L X = B where L is unit-lower from packed LU. One grid cell per
     panel block (the paper's Top kernel: U_kj = L_kk^{-1} A_kj).
     Column-oriented forward substitution: once row j of X is final, its
     contribution L[i, j] X[j, :] leaves every row i > j."""
+    del first_ref  # read by the index maps only
     n = lu_ref.shape[0]
     lrows, lcols = _iota((n, n), 0), _iota((n, n), 1)
     xrows = _iota(b_ref.shape, 0)
@@ -109,31 +113,57 @@ def _trsm_lower_kernel(lu_ref, b_ref, o_ref, x_ref):
     o_ref[...] = x_ref[...].astype(o_ref.dtype)
 
 
-def trsm_lower_left(lu: jnp.ndarray, b: jnp.ndarray, *, bn: int = 256,
-                    interpret: bool = False) -> jnp.ndarray:
-    """X = L^{-1} B for packed-LU ``lu`` (b, b) and panel ``b`` (b, N)."""
+def _panel_solve(kernel, lu, b, *, axis: int, block: int, first,
+                 name: str, interpret: bool):
+    """Launch ``kernel`` over the panel tiles of ``b`` along ``axis`` from
+    tile ``first`` (an int or a traced int32 scalar) on; the output aliases
+    ``b``, so the tiles before ``first`` keep their input values."""
     n = lu.shape[0]
+    nt = b.shape[axis] // block
+    tile = (n, block) if axis == 1 else (block, n)
+
+    # an empty grid leaves nothing to fetch; the clamp keeps the block
+    # index of any prefetch the pipeline may issue inside the array
+    def panel(j, s):
+        t = jnp.minimum(j + s[0], nt - 1)
+        return (0, t) if axis == 1 else (t, 0)
+
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(nt - first,),
+            in_specs=[
+                pl.BlockSpec((n, n), lambda j, s: (0, 0)),
+                pl.BlockSpec(tile, panel),
+            ],
+            out_specs=pl.BlockSpec(tile, panel),
+            scratch_shapes=[pltpu.VMEM(tile, jnp.float32)],
+        ),
+        out_shape=jax.ShapeDtypeStruct(b.shape, b.dtype),
+        input_output_aliases={2: 0},
+        name=name,
+        interpret=interpret,
+    )(jnp.asarray(first, jnp.int32)[None], lu, b)
+
+
+def trsm_lower_left(lu: jnp.ndarray, b: jnp.ndarray, *, bn: int = 256,
+                    first=0, interpret: bool = False) -> jnp.ndarray:
+    """X = L^{-1} B for packed-LU ``lu`` (b, b) and panel ``b`` (b, N), on
+    the column tiles from ``first`` on (an int or a traced int32 scalar, at
+    most the tile count); the tiles before it keep B's values."""
     N = b.shape[1]
     bn = fit_block(N, bn, 1 if interpret else LANE)
-    return pl.pallas_call(
-        _trsm_lower_kernel,
-        grid=(N // bn,),
-        in_specs=[
-            pl.BlockSpec((n, n), lambda j: (0, 0)),
-            pl.BlockSpec((n, bn), lambda j: (0, j)),
-        ],
-        out_specs=pl.BlockSpec((n, bn), lambda j: (0, j)),
-        out_shape=jax.ShapeDtypeStruct(b.shape, b.dtype),
-        scratch_shapes=[pltpu.VMEM((n, bn), jnp.float32)],
-        name="trsm_lower_left",
-        interpret=interpret,
-    )(lu, b)
+    return _panel_solve(_trsm_lower_kernel, lu, b, axis=1, block=bn,
+                        first=first, name="trsm_lower_left",
+                        interpret=interpret)
 
 
-def _trsm_upper_kernel(lu_ref, b_ref, o_ref, x_ref):
+def _trsm_upper_kernel(first_ref, lu_ref, b_ref, o_ref, x_ref):
     """Solve X U = B for U upper from packed LU (the paper's Left kernel:
     L_ik = A_ik U_kk^{-1}). Column j of X is final once divided by U[j, j];
     it then leaves every column i > j through U[j, i]."""
+    del first_ref  # read by the index maps only
     n = lu_ref.shape[0]
     urows = _iota((n, n), 0)
     lane = _iota((1, n), 1)
@@ -154,21 +184,12 @@ def _trsm_upper_kernel(lu_ref, b_ref, o_ref, x_ref):
 
 
 def trsm_upper_right(lu: jnp.ndarray, b: jnp.ndarray, *, bm: int = 256,
-                     interpret: bool = False) -> jnp.ndarray:
-    """X = B U^{-1} for packed-LU ``lu`` (b, b) and panel ``b`` (M, b)."""
-    n = lu.shape[0]
+                     first=0, interpret: bool = False) -> jnp.ndarray:
+    """X = B U^{-1} for packed-LU ``lu`` (b, b) and panel ``b`` (M, b), on
+    the row tiles from ``first`` on (an int or a traced int32 scalar, at
+    most the tile count); the tiles before it keep B's values."""
     M = b.shape[0]
     bm = fit_block(M, bm, 1 if interpret else SUBLANE)
-    return pl.pallas_call(
-        _trsm_upper_kernel,
-        grid=(M // bm,),
-        in_specs=[
-            pl.BlockSpec((n, n), lambda i: (0, 0)),
-            pl.BlockSpec((bm, n), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((bm, n), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct(b.shape, b.dtype),
-        scratch_shapes=[pltpu.VMEM((bm, n), jnp.float32)],
-        name="trsm_upper_right",
-        interpret=interpret,
-    )(lu, b)
+    return _panel_solve(_trsm_upper_kernel, lu, b, axis=0, block=bm,
+                        first=first, name="trsm_upper_right",
+                        interpret=interpret)

@@ -34,8 +34,11 @@ def matmul(a, b, *, bm=256, bn=256, bk=256, out_dtype=None):
 
 @partial(jax.jit, static_argnames=("alpha", "bm", "bn", "bk"),
          donate_argnums=(0,))
-def gemm_update(c, a, b, *, alpha=-1.0, bm=256, bn=256, bk=256):
+def gemm_update(c, a, b, *, alpha=-1.0, bm=256, bn=256, bk=256, first=None):
+    """``first``: the traced (row, column) tile to start from; None keeps
+    the static full grid."""
     return _gemm.gemm_update(c, a, b, alpha=alpha, bm=bm, bn=bn, bk=bk,
+                             first=(0, 0) if first is None else first,
                              interpret=interpret_mode())
 
 
@@ -51,13 +54,17 @@ def lu_factor_block(a):
 
 
 @partial(jax.jit, static_argnames=("bn",))
-def trsm_lower_left(lu, b, *, bn=256):
-    return _lu.trsm_lower_left(lu, b, bn=bn, interpret=interpret_mode())
+def trsm_lower_left(lu, b, *, bn=256, first=None):
+    return _lu.trsm_lower_left(lu, b, bn=bn,
+                               first=0 if first is None else first,
+                               interpret=interpret_mode())
 
 
 @partial(jax.jit, static_argnames=("bm",))
-def trsm_upper_right(lu, b, *, bm=256):
-    return _lu.trsm_upper_right(lu, b, bm=bm, interpret=interpret_mode())
+def trsm_upper_right(lu, b, *, bm=256, first=None):
+    return _lu.trsm_upper_right(lu, b, bm=bm,
+                                first=0 if first is None else first,
+                                interpret=interpret_mode())
 
 
 @partial(jax.jit, static_argnames=("causal", "q_offset", "bq", "bk"))

@@ -37,17 +37,48 @@ def test_matmul_sweep(rng, dtype, m, k, n, bm, bn, bk):
                                atol=ATOL[dtype] * k ** 0.5, rtol=1e-2)
 
 
+def _lead(x, first, block):
+    """The mask of the tiles of ``x`` before tile ``first`` (one index per
+    axis, tiles of ``block``): what a launch from ``first`` leaves alone."""
+    lead = np.zeros(x.shape, bool)
+    for ax, (f, t) in enumerate(zip(first, block)):
+        idx = [slice(None)] * x.ndim
+        idx[ax] = slice(0, f * t)
+        lead[tuple(idx)] = True
+    return lead
+
+
+# first (row, column) tile of the 4 x 4 tile grid: the whole grid, the
+# middle, the last tile, one past it (an empty grid), and unequal starts
+# (a 2x2 torus device whose rows and columns finish at different k)
+GEMM_FIRST = [(0, 0), (2, 2), (3, 3), (4, 4), (1, 3), (3, 0)]
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("alpha", [-1.0, 0.5])
-def test_gemm_update(rng, dtype, alpha):
-    m, k, n = 128, 96, 64
+@pytest.mark.parametrize("first", GEMM_FIRST)
+def test_gemm_update(rng, dtype, alpha, first):
+    """Over the trailing tiles only: with the panels masked as HPL masks
+    them, the result equals the full-grid call bit for bit, the visited
+    tiles match the reference, and the tiles before ``first`` keep C."""
+    m, k, n, bm, bn = 256, 96, 128, 64, 32
     c = _rand(rng, (m, n), dtype)
-    a = _rand(rng, (m, k), dtype)
-    b = _rand(rng, (k, n), dtype)
-    out = ops.gemm_update(c.copy(), a, b, alpha=alpha, bm=64, bn=32, bk=32)
+    a = np.array(_rand(rng, (m, k), dtype), np.float32)
+    b = np.array(_rand(rng, (k, n), dtype), np.float32)
+    a[:first[0] * bm] = 0.0
+    b[:, :first[1] * bn] = 0.0
+    a, b = jnp.asarray(a, dtype), jnp.asarray(b, dtype)
+    full = ops.gemm_update(c.copy(), a, b, alpha=alpha, bm=bm, bn=bn, bk=32)
+    out = ops.gemm_update(c.copy(), a, b, alpha=alpha, bm=bm, bn=bn, bk=32,
+                          first=first)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(full),
+                                  strict=True)
+    lead = _lead(np.asarray(c), first, (bm, bn))
+    np.testing.assert_array_equal(np.asarray(out)[lead],
+                                  np.asarray(c)[lead])
     want = ref.gemm_update(c, a, b, alpha=alpha)
-    np.testing.assert_allclose(np.asarray(out, np.float32),
-                               np.asarray(want, np.float32),
+    np.testing.assert_allclose(np.asarray(out, np.float32)[~lead],
+                               np.asarray(want, np.float32)[~lead],
                                atol=ATOL[dtype] * k ** 0.5, rtol=1e-2)
 
 
@@ -74,36 +105,53 @@ def test_lu_factor_block(rng, n):
                                rtol=1e-4, atol=1e-3)
 
 
-@pytest.mark.parametrize("b_cols", [64, 192])
-def test_trsm_lower_left(rng, b_cols):
-    n = 64
+# (panel tiles, first tile): the whole panel, the middle, the last tile,
+# one past it (an empty grid)
+TRSM_FIRST = [(1, 0), (3, 0), (3, 1), (3, 2), (3, 3), (1, 1)]
+
+
+def _lu_and_rhs(rng, n, shape):
     a = rng.standard_normal((n, n)).astype(np.float32)
     a[np.arange(n), np.arange(n)] += n
     lu = ops.lu_factor_block(jnp.asarray(a))
-    rhs = _rand(rng, (n, b_cols), jnp.float32)
-    out = ops.trsm_lower_left(lu, rhs, bn=64)
-    np.testing.assert_allclose(np.asarray(out),
-                               np.asarray(ref.trsm_lower_left(lu, rhs)),
+    return lu, _rand(rng, shape, jnp.float32)
+
+
+@pytest.mark.parametrize("tiles,first", TRSM_FIRST)
+def test_trsm_lower_left(rng, tiles, first):
+    """The column tiles from ``first`` on equal the full-grid solve bit for
+    bit and solve L X = B; the tiles before it keep B."""
+    n = 64
+    lu, rhs = _lu_and_rhs(rng, n, (n, tiles * 64))
+    full = np.asarray(ops.trsm_lower_left(lu, rhs, bn=64))
+    out = np.asarray(ops.trsm_lower_left(lu, rhs, bn=64, first=first))
+    lead = _lead(full, (0, first), (n, 64))
+    np.testing.assert_array_equal(out[~lead], full[~lead], strict=True)
+    np.testing.assert_array_equal(out[lead], np.asarray(rhs)[lead])
+    np.testing.assert_allclose(full, np.asarray(ref.trsm_lower_left(lu, rhs)),
                                rtol=1e-4, atol=1e-4)
     # residual: L @ X == B
     l, _ = ref.unpack_lu(np.asarray(lu))
-    np.testing.assert_allclose(l @ np.asarray(out), np.asarray(rhs),
+    np.testing.assert_allclose(l @ full, np.asarray(rhs),
                                rtol=1e-3, atol=1e-3)
 
 
-@pytest.mark.parametrize("b_rows", [64, 192])
-def test_trsm_upper_right(rng, b_rows):
+@pytest.mark.parametrize("tiles,first", TRSM_FIRST)
+def test_trsm_upper_right(rng, tiles, first):
+    """The row tiles from ``first`` on equal the full-grid solve bit for
+    bit and solve X U = B; the tiles before it keep B."""
     n = 64
-    a = rng.standard_normal((n, n)).astype(np.float32)
-    a[np.arange(n), np.arange(n)] += n
-    lu = ops.lu_factor_block(jnp.asarray(a))
-    rhs = _rand(rng, (b_rows, n), jnp.float32)
-    out = ops.trsm_upper_right(lu, rhs, bm=64)
-    np.testing.assert_allclose(np.asarray(out),
+    lu, rhs = _lu_and_rhs(rng, n, (tiles * 64, n))
+    full = np.asarray(ops.trsm_upper_right(lu, rhs, bm=64))
+    out = np.asarray(ops.trsm_upper_right(lu, rhs, bm=64, first=first))
+    lead = _lead(full, (first, 0), (64, n))
+    np.testing.assert_array_equal(out[~lead], full[~lead], strict=True)
+    np.testing.assert_array_equal(out[lead], np.asarray(rhs)[lead])
+    np.testing.assert_allclose(full,
                                np.asarray(ref.trsm_upper_right(lu, rhs)),
                                rtol=1e-4, atol=1e-4)
     _, u = ref.unpack_lu(np.asarray(lu))
-    np.testing.assert_allclose(np.asarray(out) @ u, np.asarray(rhs),
+    np.testing.assert_allclose(full @ u, np.asarray(rhs),
                                rtol=1e-3, atol=1e-3)
 
 

@@ -17,7 +17,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.kernels import attention, gemm, lu, ring, stream, transpose
 
-F32, BF16 = jnp.float32, jnp.bfloat16
+F32, BF16, I32 = jnp.float32, jnp.bfloat16, jnp.int32
 
 
 @pytest.fixture(scope="module")
@@ -63,6 +63,19 @@ KERNELS = {
                                                          bn=256),
                         [((8192, 8192), F32), ((8192, 256), F32),
                          ((256, 8192), F32)]),
+    # HPL's trailing-tile grids: a traced first tile, so a dynamic grid
+    # bound and the first tile as scalar prefetch under Mosaic
+    "gemm_update_hpl_trailing": (
+        lambda c, a, b, f: gemm.gemm_update(c, a, b, bm=256, bn=256,
+                                            first=(f[0], f[1])),
+        [((8192, 8192), F32), ((8192, 256), F32), ((256, 8192), F32),
+         ((2,), I32)]),
+    "trsm_lower_left_b256_trailing": (
+        lambda l, x, f: lu.trsm_lower_left(l, x, first=f),
+        [((256, 256), F32), ((256, 8192), F32), ((), I32)]),
+    "trsm_upper_right_b256_trailing": (
+        lambda l, x, f: lu.trsm_upper_right(l, x, first=f),
+        [((256, 256), F32), ((8192, 256), F32), ((), I32)]),
     "matmul_f32": (lambda a, b: gemm.matmul(a, b, bm=128, bn=128, bk=128),
                    [((8192, 8192), F32), ((8192, 8192), F32)]),
     "matmul_bf16": (lambda a, b: gemm.matmul(a, b),
