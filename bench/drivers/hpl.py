@@ -7,7 +7,9 @@ seed under the HPL-AI rule: entries U(-0.5, 0.5), and +N on every global
 diagonal entry (diagonally dominant, so no pivoting is needed).
 
 Check, on the host in float64, of the whole packed LU of the last timed
-call, gathered from every chip:
+call, gathered from every chip (``bench.layout.gather``: each stack copied
+off the devices once, into its global matrix; the host work spread over
+its cores, ``bench.layout.each``):
 
 - ``resid``: HPL's normalized residual ||A x - b||_inf /
   (eps * (||A||_inf ||x||_inf + ||b||_inf) * N) of the solve through the LU,
@@ -23,8 +25,8 @@ from dataclasses import dataclass, field
 
 import numpy as np
 
-from bench.layout import (global_blocks, torus_mesh, torus_size,
-                          undistribute)
+from bench.clock import phase
+from bench.layout import each, gather, global_blocks, torus_mesh, torus_size
 
 size = torus_size
 mesh = torus_mesh
@@ -140,18 +142,17 @@ def call(st: State):
 
 
 def host_matrix(st: State) -> np.ndarray:
-    import jax
     if st.a_host is None:
-        st.a_host = undistribute(np.asarray(jax.device_get(st.a)), st.pg,
-                                 st.b)
+        st.a_host = gather(st.a, st.pg, st.b)
     return st.a_host
 
 
 def check(st: State, out) -> dict:
     """The compared numbers of the program's output, each with its limit."""
-    import jax
-    lu = undistribute(np.asarray(jax.device_get(out)), st.pg, st.b)
-    return verdict(host_matrix(st), lu, st)
+    with phase("check.copy"):
+        lu = gather(out, st.pg, st.b)
+        a = host_matrix(st)
+    return verdict(a, lu, st)
 
 
 # ---------------------------------------------------------------------------
@@ -168,6 +169,18 @@ def _rows_per_class(n: int, b: int, pg: int, rng, k: int) -> np.ndarray:
     return np.sort(np.concatenate(out))
 
 
+def _matvec64(x: np.ndarray, v: np.ndarray, rows: int = 32) -> np.ndarray:
+    """``x.astype(np.float64) @ v``, ``rows`` rows at a time through one
+    small float64 buffer, so no float64 copy of ``x`` is made."""
+    out = np.empty(x.shape[0])
+    buf = np.empty((rows, x.shape[1]))
+    for i in range(0, x.shape[0], rows):
+        part = buf[:min(rows, x.shape[0] - i)]
+        part[...] = x[i:i + rows]
+        out[i:i + rows] = part @ v
+    return out
+
+
 def _solve(lu: np.ndarray, rhs: np.ndarray, lower: bool,
            step: int = 2048) -> np.ndarray:
     """Block substitution through the unit-lower (``lower``) or upper
@@ -181,9 +194,9 @@ def _solve(lu: np.ndarray, rhs: np.ndarray, lower: bool,
     for i in starts:
         j = min(i + step, n)
         if lower and i:
-            y[i:j] -= lu[i:j, :i].astype(np.float64) @ y[:i]
+            y[i:j] -= _matvec64(lu[i:j, :i], y[:i])
         elif not lower and j < n:
-            y[i:j] -= lu[i:j, j:].astype(np.float64) @ y[j:]
+            y[i:j] -= _matvec64(lu[i:j, j:], y[j:])
         y[i:j] = solve_triangular(lu[i:j, i:j].astype(np.float64), y[i:j],
                                   lower=lower, unit_diagonal=lower,
                                   check_finite=False)
@@ -198,12 +211,16 @@ def residual(a: np.ndarray, lu: np.ndarray, step: int = 2048) -> float:
     from scipy.linalg import LinAlgError
 
     n = a.shape[0]
-    rhs = np.empty(n)
-    a_norm = 0.0
-    for i in range(0, n, step):
-        blk = a[i:i + step]
-        rhs[i:i + step] = blk.sum(1, dtype=np.float64)
-        a_norm = max(a_norm, float(np.abs(blk).sum(1, dtype=np.float64).max()))
+    rows = 64             # a task's rows: a small |A| temporary, reused
+
+    def sums(i):
+        blk = a[i:i + rows]
+        return (blk.sum(1, dtype=np.float64),
+                float(np.abs(blk).sum(1, dtype=np.float64).max()))
+
+    parts = each(sums, range(0, n, rows))
+    rhs = np.concatenate([p[0] for p in parts])
+    a_norm = max([0.0] + [p[1] for p in parts])
     with np.errstate(all="ignore"):
         try:
             x = _solve(lu, _solve(lu, rhs, lower=True), lower=False)
@@ -240,8 +257,12 @@ def verdict(a: np.ndarray, lu: np.ndarray, st: State) -> dict:
     rng = np.random.default_rng([st.seed, 0x4850])
     rows = _rows_per_class(st.n, st.b, st.pg, rng, BERR_SAMPLES)
     cols = _rows_per_class(st.n, st.b, st.pg, rng, BERR_SAMPLES)
-    return {"resid": (residual(a, lu), st.limits["resid"]),
-            "berr": (backward_error(a, lu, rows, cols), st.limits["berr"])}
+    with phase("check.resid"):
+        resid = residual(a, lu)
+    with phase("check.berr"):
+        berr = backward_error(a, lu, rows, cols)
+    return {"resid": (resid, st.limits["resid"]),
+            "berr": (berr, st.limits["berr"])}
 
 
 # ---------------------------------------------------------------------------

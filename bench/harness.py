@@ -44,6 +44,8 @@ import time
 from dataclasses import dataclass, field
 from pathlib import Path
 
+from bench.clock import phase
+
 BENCH = Path(__file__).resolve().parent
 ROOT = BENCH.parent
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
@@ -237,7 +239,8 @@ class MetricContext:
 def per_layer_metrics(cell: Cell, ctx: MetricContext) -> dict:
     out = {}
     for m in cell.per_layer:
-        val = load_module("metrics", m["name"]).compute(ctx)
+        with phase(f"metric.{m['name']}"):
+            val = load_module("metrics", m["name"]).compute(ctx)
         if val is not None:
             out[m["name"]] = {"value": val, "unit": m["unit"]}
     return out
@@ -281,12 +284,15 @@ def run(cell: Cell, *, seed: int, seconds: float, trace: bool, tiny: bool,
     if trace:
         import tempfile
         tdir = tempfile.mkdtemp(prefix="bench-trace-")
-        jax.profiler.start_trace(tdir)
+        with phase("start_trace"):
+            jax.profiler.start_trace(tdir)
     try:
-        win = run_window(cell, st, seconds, counter)
+        with phase("window"):
+            win = run_window(cell, st, seconds, counter)
     finally:
         if trace:
-            jax.profiler.stop_trace()
+            with phase("stop_trace"):
+                jax.profiler.stop_trace()
     mem = peak_bytes(devs)
     best = min(win.per_call)
     _log(f"bench calls={win.calls} window_s={win.seconds!r} "
@@ -305,18 +311,21 @@ def run(cell: Cell, *, seed: int, seconds: float, trace: bool, tiny: bool,
         "platform": devs[0].platform, "kind": devs[0].device_kind,
         "count": len(devs), "memory_peak_bytes": mem}}
     if trace:
-        t = tr.load(tdir)
+        with phase("trace.load"):
+            t = tr.load(tdir)
         import shutil
         shutil.rmtree(tdir, ignore_errors=True)
-        busy = tr.busy_ns(t)
+        with phase("trace.busy"):
+            busy = tr.busy_ns(t)
         result["device"]["busy_s"] = sum(busy.values()) / len(busy) / 1e9
         result["device"]["window_s"] = t.window_ns / 1e9
         ctx = MetricContext(cell=cell, trace=t,
                             peaks=peaks_for(devs[0].device_kind),
                             calls=win.calls)
         result["metrics"] = per_layer_metrics(cell, ctx)
-        result["breakdown"] = {"device_ops": tr.top_ops(t),
-                               "idle_gaps": tr.idle_gaps(t)}
+        with phase("trace.breakdown"):
+            result["breakdown"] = {"device_ops": tr.top_ops(t),
+                                   "idle_gaps": tr.idle_gaps(t)}
     elif not tiny:
         values = {**driver.end_to_end(cell, win.calls, win.seconds),
                   "setup_s": setup_s}
@@ -324,16 +333,15 @@ def run(cell: Cell, *, seed: int, seconds: float, trace: bool, tiny: bool,
                                          "unit": m["unit"]}
                              for m in cell.end_to_end}
 
-    t_check = time.perf_counter()
-    with jax.profiler.TraceAnnotation("bench.validate"):
+    with phase("check"), jax.profiler.TraceAnnotation("bench.validate"):
         checks = driver.check(st, win.out)
-    _log(f"bench check_s={time.perf_counter() - t_check!r}")
     del st, win
     correct = all(math.isfinite(v) and v <= lim for v, lim in checks.values())
     result = {"correct": correct, "attempted": 1, "failed": 0 if correct else 1,
               **result,
               "checks": {k: {"value": v, "limit": lim}
                          for k, (v, lim) in checks.items()}}
+    _log(f"bench to_result_s={time.perf_counter() - t_start!r}")
     for k, (v, lim) in checks.items():
         _log(f"check {k} = {v!r} limit {lim!r}")
     return result

@@ -83,29 +83,13 @@ def label(trace: tr.Trace, lines: dict, t: float) -> str:
                      *runtime_events(lines, t)])
 
 
-def gaps(trace: tr.Trace) -> list[tuple[int, float]]:
-    """Every hole in a device's busy union inside the window, as
-    (nanoseconds, middle), longest first: the holes of
-    ``trace.idle_gaps``."""
-    lo, hi = trace.window
-    out = []
-    for d in trace.ops:
-        prev = lo
-        for s, e in tr.union(tr.window_ops(trace, d)) + [(hi, hi)]:
-            if s > prev:
-                out.append((s - prev, (s + prev) / 2))
-            prev = max(prev, e)
-    out.sort(key=lambda g: -g[0])
-    return out
-
-
 def idle_gaps(trace: tr.Trace, lines: dict, k: int = 10,
               min_s: float = 0.0) -> list[list]:
     """The k longest idle gaps, and every gap of at least ``min_s``
     seconds, as [label, seconds] with the host threads' events in the
     label."""
     out = []
-    for i, (ns, mid) in enumerate(gaps(trace)):
+    for i, (ns, mid) in enumerate(tr.gaps(trace)):
         if i >= k and not (min_s > 0 and ns >= min_s * 1e9):
             break
         out.append([label(trace, lines, mid), ns / 1e9])

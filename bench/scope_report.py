@@ -91,7 +91,7 @@ def report(cell, seed: int, seconds: float, trace_dir: str) -> dict:
     out["long_gap_events"] = [
         [ns / 1e9, host_events.overlapping(lines, mid - ns / 2,
                                            mid + ns / 2)[:12]]
-        for ns, mid in host_events.gaps(t) if ns >= GAP_MIN_S * 1e9]
+        for ns, mid in tr.gaps(t) if ns >= GAP_MIN_S * 1e9]
     out["op_event_stats"] = op_event_stats(trace_dir)
     out["host_lines"] = {n: len(evs) for n, evs in lines.items()}
     return out

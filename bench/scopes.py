@@ -83,6 +83,7 @@ class Scoped:
 
     def __post_init__(self):
         self._phase = {}
+        self._members = {}
 
     def phase(self, op: str) -> str:
         if op not in self._phase:
@@ -91,28 +92,34 @@ class Scoped:
                                else phase_of(path, self.names))
         return self._phase[op]
 
-    def matches(self, op: str, phases) -> bool:
-        ph = self.phase(op)
-        return any(fnmatch.fnmatchcase(ph, p) for p in phases)
+    def members(self, phases) -> frozenset:
+        """The names of the trace's ops whose phase matches one of
+        ``phases``."""
+        key = tuple(phases)
+        if key not in self._members:
+            ops = {n for ivs in self.trace.ops.values() for n, _, _ in ivs}
+            self._members[key] = frozenset(
+                n for n in ops
+                if any(fnmatch.fnmatchcase(self.phase(n), p) for p in key))
+        return self._members[key]
 
 
 def phase_ns(sc: Scoped, phases) -> dict[int, int]:
     """Per device: summed duration of the window's ops in ``phases``."""
+    names = sc.members(phases)
     return {d: sum(e - s for n, s, e in tr.window_ops(sc.trace, d)
-                   if sc.matches(n, phases))
+                   if n in names)
             for d in sc.trace.ops}
 
 
 def phase_exposed_ns(sc: Scoped, phases) -> dict[int, int]:
     """Per device: the part of the union of the ops in ``phases`` during
-    which no op of another phase runs."""
-    out = {}
-    for d in sc.trace.ops:
-        ivs = tr.window_ops(sc.trace, d)
-        sel = [iv for iv in ivs if sc.matches(iv[0], phases)]
-        rest = [iv for iv in ivs if not sc.matches(iv[0], phases)]
-        out[d] = tr.covered_ns(sel) - tr.overlap_ns(sel, rest)
-    return out
+    which no op of another phase runs: the busy union less the others'."""
+    names = sc.members(phases)
+    busy = tr.busy_ns(sc.trace)
+    return {d: busy[d] - tr.covered_ns([iv for iv in tr.window_ops(sc.trace, d)
+                                        if iv[0] not in names])
+            for d in sc.trace.ops}
 
 
 def by_phase(sc: Scoped) -> dict[str, float]:

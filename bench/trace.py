@@ -39,10 +39,14 @@ OPS_LINE = "XLA Ops"
 
 @dataclass
 class Trace:
-    """Device ops per device id, host spans, and the traced window (ns)."""
+    """Device ops per device id, host spans, and the traced window (ns).
+    Each device's ops clipped to the window, and the union of their
+    intervals, are kept once computed: every metric of a run reads them."""
     ops: dict[int, list[Interval]]
     spans: list[Interval] = field(default_factory=list)
     window: tuple[int, int] = (0, 0)
+    _clipped: dict = field(default_factory=dict, repr=False, compare=False)
+    _union: dict = field(default_factory=dict, repr=False, compare=False)
 
     @property
     def window_ns(self) -> int:
@@ -115,27 +119,21 @@ def covered_ns(ivs) -> int:
     return sum(e - s for s, e in union(ivs))
 
 
-def overlap_ns(a, b) -> int:
-    """Length of union(a) intersected with union(b)."""
-    ua, ub = union(a), union(b)
-    i = j = total = 0
-    while i < len(ua) and j < len(ub):
-        lo = max(ua[i][0], ub[j][0])
-        hi = min(ua[i][1], ub[j][1])
-        total += max(0, hi - lo)
-        if ua[i][1] < ub[j][1]:
-            i += 1
-        else:
-            j += 1
-    return total
-
-
 def window_ops(trace: Trace, dev: int) -> list[Interval]:
-    return clip(trace.ops.get(dev, []), *trace.window)
+    if dev not in trace._clipped:
+        trace._clipped[dev] = clip(trace.ops.get(dev, []), *trace.window)
+    return trace._clipped[dev]
+
+
+def busy_union(trace: Trace, dev: int) -> list[tuple[int, int]]:
+    if dev not in trace._union:
+        trace._union[dev] = union(window_ops(trace, dev))
+    return trace._union[dev]
 
 
 def busy_ns(trace: Trace) -> dict[int, int]:
-    return {d: covered_ns(window_ops(trace, d)) for d in trace.ops}
+    return {d: sum(e - s for s, e in busy_union(trace, d))
+            for d in trace.ops}
 
 
 def idle_share(trace: Trace) -> float:
@@ -159,15 +157,12 @@ def op_ns(trace: Trace, pattern: str) -> dict[int, int]:
 
 def exposed_ns(trace: Trace, pattern: str) -> dict[int, int]:
     """Per device: the part of the matching ops' union during which no
-    other (non-matching) op runs."""
+    other (non-matching) op runs: the busy union less the others' union."""
     rx = re.compile(pattern)
-    out = {}
-    for d in trace.ops:
-        ivs = window_ops(trace, d)
-        sel = [iv for iv in ivs if rx.search(iv[0])]
-        rest = [iv for iv in ivs if not rx.search(iv[0])]
-        out[d] = covered_ns(sel) - overlap_ns(sel, rest)
-    return out
+    busy = busy_ns(trace)
+    return {d: busy[d] - covered_ns([iv for iv in window_ops(trace, d)
+                                     if not rx.search(iv[0])])
+            for d in trace.ops}
 
 
 def top_ops(trace: Trace, k: int = 10) -> list[list]:
@@ -187,16 +182,23 @@ def _label(spans, t: float) -> str:
     return min(cover)[1] if cover else "outside"
 
 
+def gaps(trace: Trace) -> list[tuple[int, float]]:
+    """Every hole in a device's busy union inside the window, as
+    (nanoseconds, middle), longest first."""
+    lo, hi = trace.window
+    out = []
+    for d in trace.ops:
+        prev = lo
+        for s, e in busy_union(trace, d) + [(hi, hi)]:
+            if s > prev:
+                out.append((s - prev, (s + prev) / 2))
+            prev = max(prev, e)
+    out.sort(key=lambda g: -g[0])
+    return out
+
+
 def idle_gaps(trace: Trace, k: int = 10) -> list[list]:
     """The k longest holes in any device's busy union inside the window,
     as [host span label, seconds]."""
-    lo, hi = trace.window
-    gaps = []
-    for d in trace.ops:
-        prev = lo
-        for s, e in union(window_ops(trace, d)) + [(hi, hi)]:
-            if s > prev:
-                gaps.append((s - prev, _label(trace.spans, (s + prev) / 2)))
-            prev = max(prev, e)
-    gaps.sort(key=lambda g: -g[0])
-    return [[label, ns / 1e9] for ns, label in gaps[:k]]
+    return [[_label(trace.spans, mid), ns / 1e9]
+            for ns, mid in gaps(trace)[:k]]

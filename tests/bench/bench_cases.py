@@ -3,7 +3,7 @@ collective-exposure reader are in bench/, and the tests run its path."""
 
 TWO_BY_TWO = {"name": "hpl.n32768.2x2", "config": "hpl-ai-fp32",
               "traffic": "n32768.grid2", "chips": 4,
-              "why": "weak scaling on a 2x2 torus"}
+              "why": "strong scaling on a 2x2 torus"}
 
 
 def spec_with_2x2() -> dict:

@@ -27,7 +27,9 @@ def test_union_and_overlap():
     ivs = [("a", 0, 10), ("b", 5, 20), ("c", 30, 40)]
     assert tr.union(ivs) == [(0, 20), (30, 40)]
     assert tr.covered_ns(ivs) == 30
-    assert tr.overlap_ns(ivs, [("x", 15, 35)]) == 10
+    # x over [15, 35) overlaps a, b and c for 10 ns: 10 ns exposed
+    t = tr.Trace(ops={0: ivs + [("x", 15, 35)]}, window=(0, 100))
+    assert tr.exposed_ns(t, "^x$") == {0: 10}
 
 
 def test_busy_and_idle_share():

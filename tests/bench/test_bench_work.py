@@ -59,3 +59,27 @@ def test_layout_matches_the_programs_block_cyclic_layout(n, b, pg):
     np.testing.assert_array_equal(shards, distribute_cyclic(x, pg, b))
     np.testing.assert_array_equal(undistribute(shards, pg, b), x)
     np.testing.assert_array_equal(undistribute_cyclic(shards, pg, b), x)
+
+
+@pytest.mark.parametrize("slab_bytes", [1, 1 << 12, 1 << 30])
+@pytest.mark.parametrize("n,b,pg", [(64, 8, 1), (64, 8, 2), (96, 16, 3)])
+def test_gather_copies_a_device_stack_into_its_global_matrix(
+        n, b, pg, slab_bytes, monkeypatch):
+    """One block row of slabs, several, and the whole stack in one."""
+    import jax.numpy as jnp
+    from bench import layout
+    monkeypatch.setattr(layout, "SLAB_BYTES", slab_bytes)
+    x = np.arange(n * n, dtype=np.float32).reshape(n, n)
+    got = layout.gather(jnp.asarray(distribute(x, pg, b)), pg, b)
+    assert got.dtype == np.float32
+    np.testing.assert_array_equal(got, x)
+
+
+def test_slab_rows_are_whole_blocks_that_divide_the_local_rows():
+    from bench.layout import SLAB_BYTES, slab_rows
+    assert slab_rows(32768, 256, 32768 * 4) == SLAB_BYTES // (32768 * 4)
+    assert slab_rows(16384, 256, 4 * 16384 * 4) == 256
+    assert slab_rows(1280, 128, 1 << 20) == 128
+    for m, b, row in [(32768, 256, 4), (1280, 128, 4 * 40), (96, 16, 4)]:
+        r = slab_rows(m, b, row)
+        assert r % b == 0 and m % r == 0
